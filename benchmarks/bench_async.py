@@ -134,7 +134,7 @@ def main() -> None:
     if r.returncode != 0 or blob is None:
         print(f"async/SUBPROCESS_FAILED,0.0,"
               f"err={r.stderr[-200:].replace(chr(10), ' ')}")
-        return
+        raise RuntimeError(f"async payload failed (exit {r.returncode})")
     results = json.loads(blob[len("ASYNC_JSON "):])
     path = os.path.join(ROOT, "BENCH_async.json")
     with open(path, "w") as f:

@@ -60,7 +60,7 @@ def main():
     if "SPMD_BENCH_DONE" not in r.stdout:
         print(f"distributed/SUBPROCESS_FAILED,0.0,"
               f"err={r.stderr[-200:].replace(chr(10), ' ')}")
-        return
+        raise RuntimeError(f"distributed payload failed (exit {r.returncode})")
     for line in r.stdout.splitlines():
         if "," in line and not line.startswith("SPMD"):
             print(line, flush=True)

@@ -154,7 +154,7 @@ def main() -> None:
     if r.returncode != 0 or blob is None:
         print(f"dynamic/SUBPROCESS_FAILED,0.0,"
               f"err={r.stderr[-200:].replace(chr(10), ' ')}")
-        return
+        raise RuntimeError(f"dynamic payload failed (exit {r.returncode})")
     results = json.loads(blob[len("DYNAMIC_JSON "):])
     path = os.path.join(ROOT, "BENCH_dynamic.json")
     with open(path, "w") as f:

@@ -17,7 +17,6 @@ import time                                            # noqa: E402
 import jax                                             # noqa: E402
 import jax.numpy as jnp                                # noqa: E402
 import numpy as np                                     # noqa: E402
-from jax.experimental.shard_map import shard_map       # noqa: E402
 from jax.sharding import PartitionSpec as P            # noqa: E402
 
 from repro.core import coordination as C               # noqa: E402
@@ -116,14 +115,14 @@ def push_once(h, es, ed, em):
 
 x = jnp.asarray(np.random.default_rng(0).normal(
     size=(sg.n_local * N_DEV, F)), jnp.float32)
-pull_j = jax.jit(shard_map(
+pull_j = jax.jit(jax.shard_map(
     pull_once, mesh=mesh,
     in_specs=(h_loc_spec, P(PR.AXIS), P(PR.AXIS), P(PR.AXIS)),
-    out_specs=h_loc_spec, check_rep=False))
-push_j = jax.jit(shard_map(
+    out_specs=h_loc_spec, check_vma=False))
+push_j = jax.jit(jax.shard_map(
     push_once, mesh=mesh,
     in_specs=(h_loc_spec, P(PR.AXIS), P(PR.AXIS), P(PR.AXIS)),
-    out_specs=h_loc_spec, check_rep=False))
+    out_specs=h_loc_spec, check_vma=False))
 cb_pull = coll_of(pull_j, x, sg.edge_src_g, sg.edge_dst_l, sg.edge_mask)
 cb_push = coll_of(push_j, x, push_layout["edge_src_l"],
                   push_layout["edge_dst_g"], push_layout["edge_mask"])
@@ -172,9 +171,9 @@ def make(coord):
         grads = {"w": gseed * jnp.ones((256, 256))}
         return C.COORDINATORS[coord](sgd, w, grads, s)
 
-    return jax.jit(shard_map(body, mesh=mesh,
+    return jax.jit(jax.shard_map(body, mesh=mesh,
                              in_specs=(P(), P(), P(PR.AXIS)),
-                             out_specs=(P(), P()), check_rep=False))
+                             out_specs=(P(), P()), check_vma=False))
 
 
 gseed = jnp.arange(N_DEV, dtype=jnp.float32)

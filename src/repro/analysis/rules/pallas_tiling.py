@@ -14,11 +14,14 @@ module constants, and un-reassigned parameter defaults, and checks
 * last dim: multiple of 128, or an 8-aligned sliver below 128 (the
   ``_pick_bf`` narrow-feature rule); a last dim of 1 pads to a full
   lane-tile (127/128 waste) and is flagged — EXCEPT the codified
-  scalar-accumulator idiom: a 2-D ``pltpu.VMEM`` scratch ``(rows, 1)``
-  with sublane-aligned rows (online-softmax running max/denominator in
-  ``kernels/flash_attention.py`` and ``kernels/gat_fused.py``), where
-  one scalar per row is inherent to the algorithm and the lane padding
-  is the cost of keeping the reduction in VMEM;
+  per-row column idiom: a 2-D ``(rows, 1)`` block or scratch with
+  sublane-aligned rows, where one scalar per row is inherent.  Two
+  uses: the online-softmax running max/denominator scratch
+  (``kernels/flash_attention.py``, ``kernels/gat_fused.py``), and the
+  per-edge ids, coefficients and masks the aggregation kernels read
+  beside a ``(rows, F)`` block (``kernels/segment_sum.py``).  The TPU
+  compiler refuses a 1-D ``(rows,)`` block of a 1-D array (XLA tiles it
+  by 1024, Mosaic by 128), so the column is the layout it accepts;
 * second-to-last dim: multiple of 8 (or 1 for broadcast/leading axes);
 * fully-resolved ``pltpu.VMEM`` scratch shapes: byte size within the
   module's ``VMEM_BUDGET`` (default 8 MiB).
@@ -90,26 +93,25 @@ class PallasTilingRule(Rule):
         last = dims[-1]
         if last is not None:
             if last == 1 and len(dims) > 1:
-                # codified exception: a 2-D VMEM scalar accumulator
-                # (rows, 1) with sublane-aligned rows — the online-
-                # softmax running max/denominator idiom (flash_attention,
-                # gat_fused).  BlockSpec last-dim-1 (an HBM block shaped
-                # around a scalar column) and misaligned-row scratches
-                # stay flagged.
-                # unresolvable rows are skipped, never guessed (the
-                # in-kernel _assert_vmem covers runtime-computed tiles)
+                # codified exception: a 2-D (rows, 1) column with
+                # sublane-aligned rows — one scalar per row (per-edge
+                # ids/coefficients/masks, online-softmax running
+                # max/denominator).  Misaligned rows and higher-rank
+                # blocks with a trailing 1 stay flagged; unresolvable
+                # rows are skipped, never guessed (the in-kernel
+                # _assert_vmem covers runtime-computed tiles)
                 sub0 = dims[-2]
-                scalar_acc = (kind == "VMEM" and len(dims) == 2
-                              and (sub0 is None or sub0 % SUBLANE == 0))
-                if not scalar_acc:
+                column = (len(dims) == 2
+                          and (sub0 is None or sub0 % SUBLANE == 0))
+                if not column:
                     out.append(Finding(
                         self.rule_id, ctx.path, call.lineno,
                         f"{kind} last dim is 1: the lane axis pads to a "
                         f"full {LANE}-wide tile ({LANE - 1}/{LANE} of "
                         f"the block wasted) — widen the tile; the only "
-                        f"codified exception is a 2-D VMEM scalar "
-                        f"accumulator (rows, 1) with {SUBLANE}-aligned "
-                        f"rows (online-softmax running max/denominator)"))
+                        f"codified exception is a 2-D per-row column "
+                        f"(rows, 1) with {SUBLANE}-aligned rows (edge "
+                        f"ids/coefficients, online-softmax state)"))
             elif last > 1 and last % LANE != 0 and not (
                     last < LANE and last % SUBLANE == 0):
                 out.append(Finding(

@@ -1,7 +1,7 @@
 """RL001 — cross-device collective reachable inside a differentiated
 function (the PR 2 double-psum gradient-scaling class).
 
-Under ``shard_map(..., check_rep=False)`` the transpose of
+Under ``shard_map(..., check_vma=False)`` the transpose of
 ``jax.lax.psum`` is *another* ``psum``: a collective inside the function
 handed to ``jax.grad``/``jax.value_and_grad`` silently scales every
 gradient by the axis size.  Adam's scale-invariance masks the bug from
@@ -76,7 +76,7 @@ class PsumInGradRule(Rule):
                         f"`{cn}` is reachable (via `{via}`) from "
                         f"`{label}`, which is differentiated at line "
                         f"{node.lineno}: under shard_map "
-                        f"check_rep=False the transpose inserts a "
+                        f"check_vma=False the transpose inserts a "
                         f"second collective, scaling gradients by the "
                         f"axis size (PR 2 double-psum class) — move "
                         f"the collective outside the differentiated "

@@ -23,7 +23,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.launch import sharding as shd
@@ -71,7 +70,7 @@ def make_p3_train_step(optimizer, n_dev: int, n_layers: int = 2):
         n_pad = x_f.shape[0]
         n_local = n_pad // n_dev
         # psum the (parameter-free) count OUTSIDE the differentiated
-        # function: under check_rep=False a psum inside loss_fn transposes
+        # function: under check_vma=False a psum inside loss_fn transposes
         # to a second psum, scaling every gradient by n_dev (the PR 2
         # double-psum class, masked by Adam scale-invariance — see
         # propagation.py; statically enforced by lint rule RL001)
@@ -114,12 +113,12 @@ def make_p3_train_step(optimizer, n_dev: int, n_layers: int = 2):
     ospec = [{"w": P(AXIS, None) if i == 0 else rep, "b": rep}
              for i in range(n_layers)]
     opt_spec = {"m": pspec, "v": pspec, "step": rep}  # moments mirror params
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(pspec, opt_spec, P(None, AXIS), rep, rep, rep, rep,
                   P(AXIS), P(AXIS)),
         out_specs=(ospec, opt_spec, rep),
-        check_rep=False)
+        check_vma=False)
     return mesh, smapped
 
 
@@ -201,12 +200,12 @@ def moe_expert_parallel(cfg, p, x, *, capacity_factor: float = 1.25):
         return y.reshape(x_in.shape)
 
     xspec = P(batch_ax, None, None)
-    out = shard_map(
+    out = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(xspec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=xspec,
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_in"], p["w_out"])
 
     if cfg.num_shared_experts:

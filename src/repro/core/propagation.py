@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import partitioning as part_mod
 from repro.core.abstraction import DeviceGraph, gather_scale_segment_sum
@@ -288,7 +287,7 @@ def make_distributed_gcn_step(optimizer, n_dev: int, *, mode: str = "pull",
                   labels, lmask):
             n_local = x.shape[0]
             # psum the (parameter-free) count OUTSIDE the differentiated
-            # function: under check_rep=False a psum inside loss_fn
+            # function: under check_vma=False a psum inside loss_fn
             # transposes to another psum, scaling gradients by n_dev
             # (masked by Adam scale-invariance + clipping, caught by the
             # gradient-equivalence matrix in tests/distributed_train_check)
@@ -311,11 +310,11 @@ def make_distributed_gcn_step(optimizer, n_dev: int, *, mode: str = "pull",
 
         rep = P()
         shard = P(AXIS)
-        smapped = shard_map(
+        smapped = jax.shard_map(
             pstep, mesh=mesh,
             in_specs=(rep, rep, shard, shard, shard, shard, shard, rep,
                       shard, shard),
-            out_specs=(rep, rep, rep), check_rep=False)
+            out_specs=(rep, rep, rep), check_vma=False)
 
         def train_step(params, opt_state, sg: ShardedGraph, *,
                        push_arrays: dict, halo_cache=None):
@@ -332,7 +331,7 @@ def make_distributed_gcn_step(optimizer, n_dev: int, *, mode: str = "pull",
         indeg_l = indeg
         outdeg_all = outdeg  # replicated (N_pad,)
         # count psum'd outside the VJP (see pstep: psum-in-loss_fn would
-        # scale gradients by n_dev under check_rep=False)
+        # scale gradients by n_dev under check_vma=False)
         cnt = jnp.maximum(jax.lax.psum(jnp.sum(lmask), AXIS), 1.0)
 
         def loss_fn(p):
@@ -353,12 +352,12 @@ def make_distributed_gcn_step(optimizer, n_dev: int, *, mode: str = "pull",
 
     pspec = P()
     shard = P(AXIS)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(pspec, pspec, shard, shard, shard, shard, shard, pspec,
                   shard, shard, pspec),
         out_specs=(pspec, pspec, pspec),
-        check_rep=False)
+        check_vma=False)
 
     def train_step(params, opt_state, sg: ShardedGraph, halo_cache=None):
         if halo_cache is None:

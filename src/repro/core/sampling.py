@@ -2,9 +2,13 @@
 
 All samplers are host-side (numpy) and deterministic under a seed, mirroring
 the surveyed systems where sampling workers run on CPU (DistDGL, AGL).
-They emit fixed-shape, padded :class:`Block`s so every mini-batch hits the
-same jit cache entry (a TPU adaptation: the surveyed GPU systems use ragged
-buffers; XLA wants static shapes — recorded in DESIGN.md).
+They emit padded :class:`Block`s.  The fanout samplers
+(:class:`NeighborSampler`, :class:`ImportanceSampler`) keep the padded
+destination ids from one layer to the next, so their block shapes depend
+only on ``(batch, fanouts)`` and every mini-batch hits the same jit cache
+entry (a TPU adaptation: the surveyed GPU systems use ragged buffers; XLA
+compiles one program per shape).  The layer-wise samplers size the edge
+buffer by the batch's edge count.
 
 A k-layer mini-batch is a list of ``Block``s, innermost first:
 block[i] maps features over layer i: dst nodes aggregate from src nodes.
@@ -88,13 +92,13 @@ def _build_block(g: Graph, dst: np.ndarray, src_extra: np.ndarray,
 def sample_block_padded(g: Graph, gr: Graph, dst: np.ndarray, fanout: int,
                         rng_for, *, expand: np.ndarray = None,
                         picker=None) -> Block:
-    """One fixed-shape layer expansion (the serving-path primitive).
+    """One fixed-shape layer expansion, shared by the fanout training
+    samplers and the serving path.
 
-    Unlike the training samplers above, ``dst`` here is a PADDED id array
-    (-1 marks an empty slot) and the emitted block's shapes depend only on
-    ``(len(dst), fanout)``: src_cap = D*(1+fanout), edge_cap = D*fanout.
-    Every batch drawn from the same bucket therefore hits the same jit
-    cache entry.
+    ``dst`` is a PADDED id array (-1 marks an empty slot) and the emitted
+    block's shapes depend only on ``(len(dst), fanout)``: src_cap =
+    D*(1+fanout), edge_cap = D*fanout.  Every batch drawn from the same
+    bucket therefore hits the same jit cache entry.
 
     ``rng_for(node)`` must return a Generator for that node so a node's
     sampled neighborhood is stable across requests (cache consistency).
@@ -102,8 +106,9 @@ def sample_block_padded(g: Graph, gr: Graph, dst: np.ndarray, fanout: int,
     edges — serving skips expansion for embedding-cache hits.
     ``picker(node, nbr)``, when given, replaces the per-node rng pick
     entirely (the delta-aware samplers memoize picks through it; any
-    picker must stay a pure function of ``(node, nbr)`` to preserve the
-    determinism contract).
+    serving picker must stay a pure function of ``(node, nbr)`` to
+    preserve the determinism contract; :class:`NeighborSampler` draws from
+    its own seeded stream instead).
     """
     dst = np.asarray(dst, np.int64)
     dcap = len(dst)
@@ -145,7 +150,10 @@ class NeighborSampler:
     """Fixed-fanout neighbor sampling [GraphSAGE, Hamilton+ 2017].
 
     For each layer (outermost last) sample ``fanout`` in-neighbors per dst
-    node (with replacement if deg < fanout; missing → dropped via mask)."""
+    node without replacement (all of them if deg <= fanout).  Each layer
+    expands the previous layer's padded source ids with
+    :func:`sample_block_padded`, so a batch of ``B`` seeds always yields
+    blocks of ``B * prod(1 + f)`` source rows, whatever was sampled."""
 
     name = "neighbor"
 
@@ -159,26 +167,13 @@ class NeighborSampler:
         seeds = np.asarray(seeds, np.int64)
         blocks: List[Block] = []
         dst = seeds
-        for layer in reversed(range(len(self.fanouts))):
-            f = self.fanouts[layer]
-            srcs, edges = [], []
-            for d in dst:
-                nbr = self.gr.neighbors(d)   # in-neighbors of d
-                if len(nbr) == 0:
-                    continue
-                pick = nbr if len(nbr) <= f else self.rng.choice(
+        for f in reversed(self.fanouts):
+            def pick(d, nbr, f=f):
+                return nbr if len(nbr) <= f else self.rng.choice(
                     nbr, f, replace=False)
-                for s in pick:
-                    edges.append((s, d))
-                srcs.append(pick)
-            src_extra = (np.unique(np.concatenate(srcs))
-                         if srcs else np.zeros(0, np.int64))
-            src_cap = len(dst) + len(dst) * f
-            blocks.append(_build_block(
-                self.g, dst, src_extra,
-                np.asarray(edges, np.int64).reshape(-1, 2),
-                src_cap, len(dst) * f))
-            dst = blocks[-1].src_nodes[blocks[-1].src_nodes >= 0]
+            blocks.append(sample_block_padded(self.g, self.gr, dst, f, None,
+                                              picker=pick))
+            dst = blocks[-1].src_nodes
         blocks.reverse()
         return MiniBatch(blocks, seeds, blocks[0].src_nodes)
 
@@ -218,7 +213,7 @@ class ImportanceSampler(NeighborSampler):
         for layer in reversed(range(len(self.fanouts))):
             f = self.fanouts[layer]
             edges = []
-            for d in dst:
+            for d in dst[dst >= 0]:
                 scores = self._walk_scores(int(d))
                 top = sorted(scores, key=scores.get, reverse=True)[:f]
                 for s in top:
@@ -227,7 +222,7 @@ class ImportanceSampler(NeighborSampler):
             src_extra = np.unique(e[:, 0]) if len(e) else np.zeros(0, np.int64)
             blocks.append(_build_block(self.g, dst, src_extra, e,
                                        len(dst) * (1 + f), len(dst) * f))
-            dst = blocks[-1].src_nodes[blocks[-1].src_nodes >= 0]
+            dst = blocks[-1].src_nodes   # padded: shapes stay fixed
         blocks.reverse()
         return MiniBatch(blocks, seeds, blocks[0].src_nodes)
 

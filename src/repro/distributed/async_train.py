@@ -51,7 +51,6 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import telemetry
@@ -129,7 +128,7 @@ def make_async_fullgraph_step(optimizer, n_dev: int, *,
         idx = jax.lax.axis_index(AXIS)
         own_rows = (jnp.arange(n_pad, dtype=jnp.int32) // n_local) == idx
         # parameter-free count psum'd OUTSIDE the differentiated function
-        # (under check_rep=False a psum inside loss_fn transposes to a
+        # (under check_vma=False a psum inside loss_fn transposes to a
         # second psum, scaling gradients by n_dev — see propagation.py)
         cnt = jnp.maximum(jax.lax.psum(jnp.sum(lmask), AXIS), 1.0)
 
@@ -152,11 +151,11 @@ def make_async_fullgraph_step(optimizer, n_dev: int, *,
         return params, opt_state, loss, planes, res_out
 
     rep, shard = P(), P(AXIS)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(rep, rep, shard, shard, shard, shard, shard, rep,
                   shard, shard, rep, rep, rep),
-        out_specs=(rep, rep, rep, rep, rep), check_rep=False)
+        out_specs=(rep, rep, rep, rep, rep), check_vma=False)
     jitted = jax.jit(smapped)
 
     def train_step(params, opt_state, sg: ShardedGraph,

@@ -26,7 +26,6 @@ from typing import Callable, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import telemetry
@@ -168,7 +167,7 @@ def make_distributed_minibatch_step(cfg: GNNConfig, optimizer, n_dev: int,
                                       sdeg[l][0]))
         x_l, y_l, w_l = x[0], y[0], w[0]
         # global seed count has no parameter dependence, so psum it OUTSIDE
-        # the differentiated function: under check_rep=False a psum inside
+        # the differentiated function: under check_vma=False a psum inside
         # loss_fn transposes to another psum, silently scaling gradients by
         # n_dev — Adam's scale-invariance masks it, exact equivalence
         # (tests/distributed_train_check.py) does not
@@ -186,11 +185,11 @@ def make_distributed_minibatch_step(cfg: GNNConfig, optimizer, n_dev: int,
         return params, opt_state, loss
 
     rep, shard = P(), P(AXIS)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(rep, rep, shard, shard, shard, shard, shard, shard,
                   shard),
-        out_specs=(rep, rep, rep), check_rep=False)
+        out_specs=(rep, rep, rep), check_vma=False)
     jitted = jax.jit(smapped)
 
     def train_step(params, opt_state, arrays: dict):

@@ -53,8 +53,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.segment_sum import (DEFAULT_BE, DEFAULT_BN, SUBLANE,
-                                       VMEM_BUDGET, _assert_vmem, _edge_dot,
-                                       _fused_impl, _pad_edges, _pick_bf,
+                                       VMEM_BUDGET, _assert_vmem, _dot,
+                                       _edge_column, _edge_dot, _fused_impl,
+                                       _pad_edges, _pick_bf,
                                        fused_vmem_floats, hbm_bytes_jax_ops)
 
 NEG_INF = -1e30
@@ -78,17 +79,14 @@ def _gat_kernel(src_ref, dst_ref, mask_ref, hs_ref, es_ref, ed_ref, o_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    src = src_ref[:]                                    # (BE,)
-    onehot_s = (src[:, None] == jax.lax.broadcasted_iota(
+    onehot_s = (src_ref[:] == jax.lax.broadcasted_iota(
         jnp.int32, (1, sp), 1)).astype(jnp.float32)     # (BE, Sp)
-    es_e = jnp.dot(onehot_s, es_ref[:].astype(jnp.float32),
-                   preferred_element_type=jnp.float32)  # (BE, Hp)
+    es_e = _dot(onehot_s, es_ref[:].astype(jnp.float32))  # (BE, Hp)
 
-    local = dst_ref[:] - n_i * bn
-    onehot_d = (local[:, None] == jax.lax.broadcasted_iota(
+    local = dst_ref[:] - n_i * bn                       # (BE, 1)
+    onehot_d = (local == jax.lax.broadcasted_iota(
         jnp.int32, (1, bn), 1)).astype(jnp.float32)     # (BE, BN)
-    ed_e = jnp.dot(onehot_d, ed_ref[:].astype(jnp.float32),
-                   preferred_element_type=jnp.float32)  # (BE, Hp)
+    ed_e = _dot(onehot_d, ed_ref[:].astype(jnp.float32))  # (BE, Hp)
 
     pre = es_e + ed_e
     logits = jnp.where(pre >= 0, pre, LEAKY_SLOPE * pre)   # (BE, Hp)
@@ -96,7 +94,7 @@ def _gat_kernel(src_ref, dst_ref, mask_ref, hs_ref, es_ref, ed_ref, o_ref,
     # edges outside this destination tile have an all-zero one-hot row;
     # fold that into the validity so they cannot touch max/denominator
     intile = jnp.sum(onehot_d, axis=1, keepdims=True)      # (BE, 1)
-    veff = mask_ref[:].astype(jnp.float32)[:, None] * intile
+    veff = mask_ref[:] * intile                            # (BE, 1)
 
     hs = hs_ref[:].astype(jnp.float32)                     # (Sp, H*hdp)
     for h in range(heads):                                 # static unroll
@@ -107,17 +105,14 @@ def _gat_kernel(src_ref, dst_ref, mask_ref, hs_ref, es_ref, ed_ref, o_ref,
                            axis=0, keepdims=True)          # (1, BN)
         m_prev = m_scr[:, h:h + 1]                         # (BN, 1)
         m_new = jnp.maximum(m_prev, tile_max.T)
-        m_e = jnp.dot(onehot_d, m_new,
-                      preferred_element_type=jnp.float32)  # (BE, 1)
+        m_e = _dot(onehot_d, m_new)                        # (BE, 1)
         # guard: an invalid edge may see m_e = 0 or -inf; never exp it
         p = jnp.where(veff > 0.5, jnp.exp(lh - m_e), 0.0)  # (BE, 1)
         corr = jnp.exp(m_prev - m_new)                     # (BN, 1)
-        l_scr[:, h:h + 1] = corr * l_scr[:, h:h + 1] + jnp.dot(
-            onehot_d.T, p, preferred_element_type=jnp.float32)
-        msgs = jnp.dot(onehot_s, hs[:, sl],
-                       preferred_element_type=jnp.float32)  # (BE, hdp)
-        contrib = jnp.dot(onehot_d.T, p * msgs,
-                          preferred_element_type=jnp.float32)  # (BN, hdp)
+        l_scr[:, h:h + 1] = (corr * l_scr[:, h:h + 1]
+                             + _dot(onehot_d.T, p))
+        msgs = _dot(onehot_s, hs[:, sl])                   # (BE, hdp)
+        contrib = _dot(onehot_d.T, p * msgs)               # (BN, hdp)
         acc_scr[:, sl] = corr * acc_scr[:, sl] + contrib
         m_scr[:, h:h + 1] = m_new
 
@@ -150,12 +145,9 @@ def _gat_impl(hs, es, ed, edge_src, edge_dst, maskf, num_dst, heads, be,
             hs[:, h * hd:(h + 1) * hd])
     es_p = jnp.zeros((Sp, hp), es.dtype).at[:S, :heads].set(es)
     ed_p = jnp.zeros((Np, hp), ed.dtype).at[:num_dst, :heads].set(ed)
-    src_p = jnp.zeros((Ep,), jnp.int32).at[:E].set(
-        edge_src.astype(jnp.int32))
-    dst_p = jnp.full((Ep,), pad_seg, jnp.int32).at[:E].set(
-        edge_dst.astype(jnp.int32))
-    mask_p = jnp.zeros((Ep,), jnp.float32).at[:E].set(
-        maskf.astype(jnp.float32))
+    src_p = _edge_column(edge_src, Ep, 0, jnp.int32)
+    dst_p = _edge_column(edge_dst, Ep, pad_seg, jnp.int32)
+    mask_p = _edge_column(maskf, Ep, 0, jnp.float32)
 
     # hs/es slabs have a constant block index over the whole grid sweep,
     # so they cross HBM once; the ed block follows the destination tile
@@ -164,9 +156,9 @@ def _gat_impl(hs, es, ed, edge_src, edge_dst, maskf, num_dst, heads, be,
         functools.partial(_gat_kernel, bn=bn, sp=Sp, heads=heads, hdp=hdp),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((be,), lambda n, e: (e,)),
-            pl.BlockSpec((be,), lambda n, e: (e,)),
-            pl.BlockSpec((be,), lambda n, e: (e,)),
+            pl.BlockSpec((be, 1), lambda n, e: (e, 0)),
+            pl.BlockSpec((be, 1), lambda n, e: (e, 0)),
+            pl.BlockSpec((be, 1), lambda n, e: (e, 0)),
             pl.BlockSpec((Sp, heads * hdp), lambda n, e: (0, 0)),
             pl.BlockSpec((Sp, hp), lambda n, e: (0, 0)),
             pl.BlockSpec((bn, hp), lambda n, e: (n, 0)),

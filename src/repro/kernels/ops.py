@@ -1,8 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels TARGET TPU — see DESIGN.md).  On a TPU backend the same call
-sites compile the real kernels.
+The kernels target the TPU.  They run in Pallas interpret mode on the
+CPU backend only (the test suite's platform); every other backend
+compiles them, so a chip run never silently interprets.
 
 The backend is resolved *per call* in a plain-Python wrapper and passed
 into the jit as a static argument.  (The previous design read
@@ -28,8 +28,8 @@ from repro.kernels import segment_sum as _ss
 from repro.kernels import ssd_chunk as _ssd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
 
 
 # Dispatch counters.  The wrapper bodies below run when Python calls them
@@ -98,7 +98,7 @@ def segment_sum(msgs, seg_ids, num_segments: int):
     blocked gather kernel.  See :mod:`repro.kernels.segment_sum`."""
     _m_dispatch_ss.inc()
     return _segment_sum_jit(msgs, seg_ids, num_segments,
-                            interpret=not _on_tpu())
+                            interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("num_dst", "interpret"))
@@ -136,7 +136,7 @@ def gather_scale_segment_sum(h, edge_src, edge_dst, coef, num_dst: int):
     """
     S, F = h.shape
     E = len(edge_src)
-    interpret = not _on_tpu()
+    interpret = _interpret()
     if not _ss.fused_fits(S, num_dst, F):
         key = (S, num_dst, F)
         if key not in _fallback_warned:      # surface the dispatch once
@@ -178,7 +178,7 @@ def gather_scale_segment_sum_q(q, mn, scale, edge_src, edge_dst, coef,
     round-trip saving is a fits-only optimization)."""
     S, F = q.shape
     E = len(edge_src)
-    interpret = not _on_tpu()
+    interpret = _interpret()
     if not _ss.fused_fits(S, num_dst, F):
         _m_dispatch_unfused.inc()
         _m_hbm_unfused.set(
@@ -251,7 +251,7 @@ def gat_attention(hs, es, ed, edge_src, edge_dst, mask, num_dst: int, *,
     S = hs.shape[0]
     E = len(edge_src)
     hd = hs.shape[1] // heads
-    interpret = not _on_tpu()
+    interpret = _interpret()
     if not _gat.gat_fused_fits(S, num_dst, heads, hd):
         key = (S, num_dst, heads, hd)
         if key not in _gat_fallback_warned:
@@ -284,7 +284,7 @@ def _flash_attention_jit(q, k, v, causal: bool, window: int,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
     return _flash_attention_jit(q, k, v, causal, window,
-                                interpret=not _on_tpu())
+                                interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -293,4 +293,4 @@ def _ssd_chunk_state_jit(x, dt, A, Bm, interpret: bool):
 
 
 def ssd_chunk_state(x, dt, A, Bm):
-    return _ssd_chunk_state_jit(x, dt, A, Bm, interpret=not _on_tpu())
+    return _ssd_chunk_state_jit(x, dt, A, Bm, interpret=_interpret())

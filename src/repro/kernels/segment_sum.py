@@ -104,6 +104,23 @@ def _pad_edges(E: int, be: int) -> int:
     return max(-(-E // be) * be, be)
 
 
+def _edge_column(v, Ep: int, fill, dtype) -> jax.Array:
+    """Per-edge operand (ids, coefficients, masks) as an ``(Ep, 1)``
+    column padded with ``fill``.  Kernels read it in ``(be, 1)`` blocks:
+    the TPU compiler refuses 1-D ``(be,)`` blocks of a 1-D array, whose
+    XLA layout tiles by 1024 where Mosaic tiles by 128."""
+    return jnp.full((Ep, 1), fill, dtype).at[:v.shape[0], 0].set(
+        v.astype(dtype))
+
+
+def _dot(a, b):
+    """f32 matmul at full precision.  The one-hot gathers and scatters
+    must be exact; at the TPU's default precision the data operand would
+    round to bfloat16."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # forward scatter-add kernel
 # ---------------------------------------------------------------------------
@@ -113,14 +130,11 @@ def _scatter_kernel(ids_ref, msgs_ref, out_ref, acc_ref, *, bn: int):
     e_i = pl.program_id(2)
     ne = pl.num_programs(2)
 
-    ids = ids_ref[:]                                   # (BE,)
-    base = n_i * bn
-    local = ids - base
-    onehot = (local[:, None] == jax.lax.broadcasted_iota(
+    local = ids_ref[:] - n_i * bn                      # (BE, 1)
+    onehot = (local == jax.lax.broadcasted_iota(
         jnp.int32, (1, bn), 1)).astype(jnp.float32)    # (BE, BN)
     msgs = msgs_ref[:].astype(jnp.float32)             # (BE, BF)
-    contrib = jnp.dot(onehot.T, msgs,
-                      preferred_element_type=jnp.float32)  # (BN, BF)
+    contrib = _dot(onehot.T, msgs)                     # (BN, BF)
 
     @pl.when(e_i == 0)
     def _init():
@@ -145,15 +159,14 @@ def _scatter_add(msgs, seg_ids, num_segments, be, bn, bf, interpret):
     Np = -(-(num_segments + 1) // bn) * bn
 
     msgs_p = jnp.zeros((Ep, Fp), msgs.dtype).at[:E, :F].set(msgs)
-    ids_p = jnp.full((Ep,), pad_seg, jnp.int32).at[:E].set(
-        seg_ids.astype(jnp.int32))
+    ids_p = _edge_column(seg_ids, Ep, pad_seg, jnp.int32)
 
     grid = (Np // bn, Fp // bf, Ep // be)
     out = pl.pallas_call(
         functools.partial(_scatter_kernel, bn=bn),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((be,), lambda n, f, e: (e,)),
+            pl.BlockSpec((be, 1), lambda n, f, e: (e, 0)),
             pl.BlockSpec((be, bf), lambda n, f, e: (e, f)),
         ],
         out_specs=pl.BlockSpec((bn, bf), lambda n, f, e: (n, f)),
@@ -172,13 +185,11 @@ def _gather_kernel(ids_ref, gout_ref, out_ref, acc_ref, *, bn: int):
     n_i = pl.program_id(2)
     nn = pl.num_programs(2)
 
-    ids = ids_ref[:]                                   # (BE,)
-    local = ids - n_i * bn
-    onehot = (local[:, None] == jax.lax.broadcasted_iota(
+    local = ids_ref[:] - n_i * bn                      # (BE, 1)
+    onehot = (local == jax.lax.broadcasted_iota(
         jnp.int32, (1, bn), 1)).astype(jnp.float32)    # (BE, BN)
     gout = gout_ref[:].astype(jnp.float32)             # (BN, BF)
-    contrib = jnp.dot(onehot, gout,
-                      preferred_element_type=jnp.float32)  # (BE, BF)
+    contrib = _dot(onehot, gout)                       # (BE, BF)
 
     @pl.when(n_i == 0)
     def _init():
@@ -214,15 +225,14 @@ def gather_rows_pallas(grad_out, seg_ids, E, *, be=DEFAULT_BE,
     Np = -(-(N + 1) // bn) * bn        # +1: pad ids may point at row N
 
     gout_p = jnp.zeros((Np, Fp), grad_out.dtype).at[:N, :F].set(grad_out)
-    ids_p = jnp.full((Ep,), N, jnp.int32).at[:E].set(
-        seg_ids.astype(jnp.int32))
+    ids_p = _edge_column(seg_ids, Ep, N, jnp.int32)
 
     grid = (Ep // be, Fp // bf, Np // bn)
     out = pl.pallas_call(
         functools.partial(_gather_kernel, bn=bn),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((be,), lambda e, f, n: (e,)),
+            pl.BlockSpec((be, 1), lambda e, f, n: (e, 0)),
             pl.BlockSpec((bn, bf), lambda e, f, n: (n, f)),
         ],
         out_specs=pl.BlockSpec((be, bf), lambda e, f, n: (e, f)),
@@ -290,19 +300,16 @@ def _fused_kernel(src_ref, dst_ref, coef_ref, h_ref, out_ref, acc_ref, *,
     e_i = pl.program_id(2)
     ne = pl.num_programs(2)
 
-    src = src_ref[:]                                   # (BE,)
-    onehot_s = (src[:, None] == jax.lax.broadcasted_iota(
+    onehot_s = (src_ref[:] == jax.lax.broadcasted_iota(
         jnp.int32, (1, sp), 1)).astype(jnp.float32)    # (BE, Sp)
     h = h_ref[:].astype(jnp.float32)                   # (Sp, BF) resident
-    msgs = jnp.dot(onehot_s, h,
-                   preferred_element_type=jnp.float32)  # (BE, BF) VMEM-only
-    msgs = msgs * coef_ref[:].astype(jnp.float32)[:, None]
+    msgs = _dot(onehot_s, h)                           # (BE, BF) VMEM-only
+    msgs = msgs * coef_ref[:].astype(jnp.float32)      # (BE, 1) broadcast
 
-    local = dst_ref[:] - n_i * bn
-    onehot_d = (local[:, None] == jax.lax.broadcasted_iota(
+    local = dst_ref[:] - n_i * bn                      # (BE, 1)
+    onehot_d = (local == jax.lax.broadcasted_iota(
         jnp.int32, (1, bn), 1)).astype(jnp.float32)    # (BE, BN)
-    contrib = jnp.dot(onehot_d.T, msgs,
-                      preferred_element_type=jnp.float32)  # (BN, BF)
+    contrib = _dot(onehot_d.T, msgs)                   # (BN, BF)
 
     @pl.when(e_i == 0)
     def _init():
@@ -331,11 +338,9 @@ def _fused_impl(h, edge_src, edge_dst, coef, num_dst, be, bn, bf,
     Np = -(-(num_dst + 1) // bn) * bn
 
     h_p = jnp.zeros((Sp, Fp), h.dtype).at[:S, :F].set(h)
-    src_p = jnp.zeros((Ep,), jnp.int32).at[:E].set(
-        edge_src.astype(jnp.int32))
-    dst_p = jnp.full((Ep,), pad_seg, jnp.int32).at[:E].set(
-        edge_dst.astype(jnp.int32))
-    coef_p = jnp.zeros((Ep,), coef.dtype).at[:E].set(coef)
+    src_p = _edge_column(edge_src, Ep, 0, jnp.int32)
+    dst_p = _edge_column(edge_dst, Ep, pad_seg, jnp.int32)
+    coef_p = _edge_column(coef, Ep, 0, coef.dtype)
 
     # feature dimension OUTERMOST: the (Sp, bf) source slab's block index
     # is constant over the whole inner (n, e) sweep, so it is fetched
@@ -346,9 +351,9 @@ def _fused_impl(h, edge_src, edge_dst, coef, num_dst, be, bn, bf,
         functools.partial(_fused_kernel, bn=bn, sp=Sp),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((be,), lambda f, n, e: (e,)),
-            pl.BlockSpec((be,), lambda f, n, e: (e,)),
-            pl.BlockSpec((be,), lambda f, n, e: (e,)),
+            pl.BlockSpec((be, 1), lambda f, n, e: (e, 0)),
+            pl.BlockSpec((be, 1), lambda f, n, e: (e, 0)),
+            pl.BlockSpec((be, 1), lambda f, n, e: (e, 0)),
             pl.BlockSpec((Sp, bf), lambda f, n, e: (0, f)),
         ],
         out_specs=pl.BlockSpec((bn, bf), lambda f, n, e: (n, f)),
@@ -364,15 +369,13 @@ def _edge_dot_kernel(src_ref, dst_ref, h_ref, gout_ref, out_ref, acc_ref,
     f_i = pl.program_id(1)
     nf = pl.num_programs(1)
 
-    onehot_s = (src_ref[:][:, None] == jax.lax.broadcasted_iota(
+    onehot_s = (src_ref[:] == jax.lax.broadcasted_iota(
         jnp.int32, (1, sp), 1)).astype(jnp.float32)      # (BE, Sp)
-    onehot_d = (dst_ref[:][:, None] == jax.lax.broadcasted_iota(
+    onehot_d = (dst_ref[:] == jax.lax.broadcasted_iota(
         jnp.int32, (1, npd), 1)).astype(jnp.float32)     # (BE, Npd)
-    hs = jnp.dot(onehot_s, h_ref[:].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)     # (BE, BF)
-    gd = jnp.dot(onehot_d, gout_ref[:].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)     # (BE, BF)
-    part = jnp.sum(hs * gd, axis=1)                      # (BE,)
+    hs = _dot(onehot_s, h_ref[:].astype(jnp.float32))    # (BE, BF)
+    gd = _dot(onehot_d, gout_ref[:].astype(jnp.float32))  # (BE, BF)
+    part = jnp.sum(hs * gd, axis=1, keepdims=True)       # (BE, 1)
 
     @pl.when(f_i == 0)
     def _init():
@@ -402,27 +405,25 @@ def _edge_dot(h, gout, edge_src, edge_dst, be, bf, interpret):
     g_p = jnp.zeros((Npd, Fp), gout.dtype).at[:Nd, :F].set(gout)
     # pad-edge rows of the output are trimmed below, so pad ids only
     # need to be in range
-    src_p = jnp.zeros((Ep,), jnp.int32).at[:E].set(
-        edge_src.astype(jnp.int32))
-    dst_p = jnp.zeros((Ep,), jnp.int32).at[:E].set(
-        edge_dst.astype(jnp.int32))
+    src_p = _edge_column(edge_src, Ep, 0, jnp.int32)
+    dst_p = _edge_column(edge_dst, Ep, 0, jnp.int32)
 
     grid = (Ep // be, Fp // bf)
     out = pl.pallas_call(
         functools.partial(_edge_dot_kernel, sp=Sp, npd=Npd),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((be,), lambda e, f: (e,)),
-            pl.BlockSpec((be,), lambda e, f: (e,)),
+            pl.BlockSpec((be, 1), lambda e, f: (e, 0)),
+            pl.BlockSpec((be, 1), lambda e, f: (e, 0)),
             pl.BlockSpec((Sp, bf), lambda e, f: (0, f)),
             pl.BlockSpec((Npd, bf), lambda e, f: (0, f)),
         ],
-        out_specs=pl.BlockSpec((be,), lambda e, f: (e,)),
-        out_shape=jax.ShapeDtypeStruct((Ep,), h.dtype),
-        scratch_shapes=[pltpu.VMEM((be,), jnp.float32)],
+        out_specs=pl.BlockSpec((be, 1), lambda e, f: (e, 0)),
+        out_shape=jax.ShapeDtypeStruct((Ep, 1), h.dtype),
+        scratch_shapes=[pltpu.VMEM((be, 1), jnp.float32)],
         interpret=interpret,
     )(src_p, dst_p, h_p, g_p)
-    return out[:E]
+    return out[:E, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -496,24 +497,22 @@ def _fused_q_kernel(src_ref, dst_ref, coef_ref, q_ref, meta_ref, out_ref,
     e_i = pl.program_id(2)
     ne = pl.num_programs(2)
 
-    src = src_ref[:]                                   # (BE,)
-    onehot_s = (src[:, None] == jax.lax.broadcasted_iota(
+    onehot_s = (src_ref[:] == jax.lax.broadcasted_iota(
         jnp.int32, (1, sp), 1)).astype(jnp.float32)    # (BE, Sp)
     # dequantize the resident int8 slab in VMEM: the fp32 rows exist
-    # only here, never in HBM (the wire payload feeds the kernel as-is)
-    q = q_ref[:].astype(jnp.float32)                   # (Sp, BF)
+    # only here, never in HBM (the wire payload feeds the kernel as-is).
+    # Mosaic has no uint8 -> float32 cast; widening through int32 is exact
+    q = q_ref[:].astype(jnp.int32).astype(jnp.float32)  # (Sp, BF)
     mn = meta_ref[:, 0:1]                              # (Sp, 1)
     scale = meta_ref[:, 1:2]                           # (Sp, 1)
     h = mn + q * scale
-    msgs = jnp.dot(onehot_s, h,
-                   preferred_element_type=jnp.float32)  # (BE, BF)
-    msgs = msgs * coef_ref[:].astype(jnp.float32)[:, None]
+    msgs = _dot(onehot_s, h)                           # (BE, BF)
+    msgs = msgs * coef_ref[:]                          # (BE, 1) broadcast
 
-    local = dst_ref[:] - n_i * bn
-    onehot_d = (local[:, None] == jax.lax.broadcasted_iota(
+    local = dst_ref[:] - n_i * bn                      # (BE, 1)
+    onehot_d = (local == jax.lax.broadcasted_iota(
         jnp.int32, (1, bn), 1)).astype(jnp.float32)    # (BE, BN)
-    contrib = jnp.dot(onehot_d.T, msgs,
-                      preferred_element_type=jnp.float32)  # (BN, BF)
+    contrib = _dot(onehot_d.T, msgs)                   # (BN, BF)
 
     @pl.when(e_i == 0)
     def _init():
@@ -569,21 +568,18 @@ def gather_scale_segment_sum_q_pallas(q: jax.Array, mn: jax.Array,
     meta_p = jnp.zeros((Sp, META_COLS), jnp.float32)
     meta_p = meta_p.at[:S, 0:1].set(mn.astype(jnp.float32))
     meta_p = meta_p.at[:S, 1:2].set(scale.astype(jnp.float32))
-    src_p = jnp.zeros((Ep,), jnp.int32).at[:E].set(
-        edge_src.astype(jnp.int32))
-    dst_p = jnp.full((Ep,), pad_seg, jnp.int32).at[:E].set(
-        edge_dst.astype(jnp.int32))
-    coef_p = jnp.zeros((Ep,), jnp.float32).at[:E].set(
-        coef.astype(jnp.float32))
+    src_p = _edge_column(edge_src, Ep, 0, jnp.int32)
+    dst_p = _edge_column(edge_dst, Ep, pad_seg, jnp.int32)
+    coef_p = _edge_column(coef, Ep, 0, jnp.float32)
 
     grid = (Fp // bf, Np // bn, Ep // be)
     out = pl.pallas_call(
         functools.partial(_fused_q_kernel, bn=bn, sp=Sp),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((be,), lambda f, n, e: (e,)),
-            pl.BlockSpec((be,), lambda f, n, e: (e,)),
-            pl.BlockSpec((be,), lambda f, n, e: (e,)),
+            pl.BlockSpec((be, 1), lambda f, n, e: (e, 0)),
+            pl.BlockSpec((be, 1), lambda f, n, e: (e, 0)),
+            pl.BlockSpec((be, 1), lambda f, n, e: (e, 0)),
             pl.BlockSpec((Sp, bf), lambda f, n, e: (0, f)),
             pl.BlockSpec((Sp, META_COLS), lambda f, n, e: (0, 0)),
         ],

@@ -139,10 +139,12 @@ def run(args):
     import numpy as np
 
     from repro.graph import generators as G
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.gnn import model as GM
     from repro.models.gnn.model import GNNConfig
     from repro.serving import GNNInferenceServer, poisson_workload
 
+    enable_compile_cache()
     if args.dataset:
         from repro.graph.datasets import load
         g = load(args.dataset, seed=args.seed).graph

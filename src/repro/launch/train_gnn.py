@@ -132,6 +132,19 @@ def resolve_edge_cut(g, n_dev: int, method: str) -> str:
     return method
 
 
+def build_graph(args):
+    """The training graph: the named ``--dataset``, or an SBM with
+    ``--nodes`` nodes, ``--classes`` communities and ``--feat-dim``
+    features, generated from ``--seed``."""
+    from repro.graph import generators as G
+    if args.dataset:
+        from repro.graph.datasets import load
+        return load(args.dataset, seed=args.seed).graph
+    g = G.sbm(args.nodes, args.classes, p_in=0.9, p_out=0.02,
+              seed=args.seed)
+    return G.featurize(g, args.feat_dim, seed=args.seed, class_sep=1.5)
+
+
 def main(argv=None):
     """Parse args, run the selected training path, and (when asked) dump
     the telemetry plane on exit — metrics as Prometheus text, spans as
@@ -186,24 +199,23 @@ def run(args):
     from repro.core.abstraction import DeviceGraph
     from repro.core.scheduling import PipelinedLoader
     from repro.core.sync import HaloCache, SyncPolicy
-    from repro.graph import generators as G
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.gnn import model as GM
     from repro.models.gnn.model import GNNConfig
     from repro.optim import AdamW
 
+    if args.devices > jax.device_count():
+        raise SystemExit(f"--devices {args.devices}: only "
+                         f"{jax.device_count()} {jax.default_backend()} "
+                         f"device(s) visible")
+    n_dev = args.devices
+    enable_compile_cache()
     rng = np.random.default_rng(args.seed)
-    if args.dataset:
-        from repro.graph.datasets import load
-        ds = load(args.dataset, seed=args.seed)
-        g = ds.graph
-        feat_dim = g.features.shape[1]
-    else:
-        g = G.sbm(args.nodes, args.classes, p_in=0.9, p_out=0.02,
-                  seed=args.seed)
-        g = G.featurize(g, args.feat_dim, seed=args.seed, class_sep=1.5)
-        feat_dim = args.feat_dim
+    g = build_graph(args)
+    feat_dim = g.features.shape[1]
     print(f"graph: {g.num_nodes} nodes, {g.num_edges} edges, "
-          f"{g.num_classes} classes; devices={jax.device_count()}")
+          f"{g.num_classes} classes, {feat_dim} features; "
+          f"devices={jax.device_count()}")
 
     reorder_inv = None
     if args.reorder != "none":
@@ -237,7 +249,6 @@ def run(args):
         if args.arch != "gcn":
             raise SystemExit("--fullgraph implements GCN (like the "
                              "synchronous distributed full-graph mode)")
-        n_dev = min(args.devices, jax.device_count())
         method = resolve_edge_cut(g, n_dev, args.partitioner)
         trainer = AsyncFullGraphTrainer(
             g, cfg, opt, n_dev, partitioner=method,
@@ -304,7 +315,6 @@ def run(args):
         if args.arch != "gcn":
             raise SystemExit("distributed full-graph mode implements GCN; "
                              "use --minibatch for other architectures")
-        n_dev = min(args.devices, jax.device_count())
         method = resolve_edge_cut(g, n_dev, args.partitioner)
         sg = PR.shard_graph(g, n_dev, method=method)
 
@@ -357,7 +367,6 @@ def run(args):
         if args.sampler not in ("neighbor",):
             raise SystemExit("distributed mini-batch uses the padded "
                              "neighbor sampler (--sampler neighbor)")
-        n_dev = min(args.devices, jax.device_count())
         method = resolve_edge_cut(g, n_dev, args.partitioner)
         dsampler = DistributedMinibatchSampler(
             g, n_dev, [5, 5], args.batch, partitioner=method,
@@ -428,7 +437,7 @@ def run(args):
         "train_step_seconds", "wall time per executed training step",
         mode="minibatch_single")
     for epoch in range(args.epochs):
-        for _ in range(steps_per_epoch):
+        for i in range(steps_per_epoch):
             mb, seeds = next(loader)
             t0 = time.perf_counter()
             with telemetry.span("train.step", mode="minibatch_single"):
@@ -449,6 +458,10 @@ def run(args):
                 params, ostate, loss = step(params, ostate, blocks, x_in,
                                             y, jnp.ones_like(y, jnp.float32))
             m_step.observe(time.perf_counter() - t0)
+            if epoch == 0 and i == 0:
+                print(f"step 0 loss {float(loss):.4f} "
+                      f"({time.perf_counter() - t0:.1f} s, compile "
+                      f"included)")
         print(f"epoch {epoch:3d} loss {float(loss):.4f} "
               f"cache_hit {store.hit_ratio:.2%} "
               f"fetched {store.transferred_bytes / 2**20:.1f} MiB")

@@ -1,7 +1,7 @@
 """RL001 true positive: psum reachable inside a differentiated function.
 
 This is the PR 2 bug verbatim in miniature — under shard_map
-check_rep=False, the transpose of the psum is a second psum, so the
+check_vma=False, the transpose of the psum is a second psum, so the
 gradients come back scaled by the axis size.
 """
 import jax

@@ -1,10 +1,8 @@
-"""RL004 scalar-accumulator idiom — the shapes that do NOT qualify.
+"""RL004 per-row column idiom — the shapes that do NOT qualify.
 
-The codified exception is narrow: a 2-D ``pltpu.VMEM`` scratch
-``(rows, 1)`` with sublane-aligned rows.  Everything adjacent to it
-stays flagged: misaligned rows, a 3-D scratch with a trailing 1, and a
-``pl.BlockSpec`` shaped around a scalar column (an HBM block, not a
-VMEM accumulator).
+The codified exception is narrow: a 2-D ``(rows, 1)`` block or scratch
+with sublane-aligned rows.  Everything adjacent to it stays flagged:
+misaligned rows, and a 3-D scratch or block with a trailing 1.
 """
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -20,6 +18,5 @@ def scratch():
 
 
 def spec():
-    # BAD: BlockSpec last-dim-1 is never exempt — a scalar column in HBM
-    # should ride along a wider block, not get its own lane tile
-    return pl.BlockSpec((8, 1), lambda i: (i, 0))
+    # BAD: a 3-D block with a trailing 1 is not a per-row column
+    return pl.BlockSpec((2, 8, 1), lambda i: (i, 0, 0))

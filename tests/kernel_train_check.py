@@ -16,7 +16,7 @@ custom VJPs matches the XLA autodiff path step for step.
 * ``n_dev > 1`` uses the distributed pull step
   (:func:`repro.core.propagation.make_distributed_gcn_step`), which
   exercises the fused kernel *inside shard_map* — custom VJP under
-  ``check_rep=False`` with psum'd gradients.
+  ``check_vma=False`` with psum'd gradients.
 """
 import os
 import sys

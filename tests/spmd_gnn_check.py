@@ -104,7 +104,7 @@ late = abs(dlosses[-1] - rlosses[-1]) / rlosses[-1]
 assert early < 1e-4, (dlosses, rlosses)
 assert late < 0.01, (dlosses, rlosses)
 # parameter-level equivalence after 10 steps: the guard for gradient
-# scaling bugs (e.g. psum inside loss_fn under check_rep=False multiplies
+# scaling bugs (e.g. psum inside loss_fn under check_vma=False multiplies
 # grads by n_dev) that Adam's scale-invariance + clipping hide from the
 # EARLY loss trajectory entirely and leave late_rel at only ~0.04
 pdiff = max(
@@ -168,7 +168,6 @@ assert err3 < 1e-2, (p3_losses, rlosses[:10])
 print(f"PASS p3-hybrid maxerr={err3:.2e}")
 
 # --- coordination: PS == all-reduce ---------------------------------------
-from jax.experimental.shard_map import shard_map      # noqa: E402
 from jax.sharding import PartitionSpec as P           # noqa: E402
 from repro.core import coordination as C              # noqa: E402
 
@@ -186,9 +185,9 @@ def run(coord):
         grads = {"w": gseed * jnp.ones((4, 4))}
         return C.COORDINATORS[coord](sgd, w, grads, s)
 
-    f = shard_map(body, mesh=mesh,
+    f = jax.shard_map(body, mesh=mesh,
                   in_specs=(P(), P(), P(PR.AXIS)),
-                  out_specs=(P(), P()), check_rep=False)
+                  out_specs=(P(), P()), check_vma=False)
     gseed = jnp.arange(8, dtype=jnp.float32).reshape(8)
     return jax.jit(f)(w0, s0, gseed)
 

@@ -77,8 +77,8 @@ def test_rl004_flags_each_shape_class():
 
 
 def test_rl004_scalar_accumulator_idiom_is_narrow():
-    """The (rows, 1) VMEM exemption must not leak: BlockSpec last-dim-1,
-    misaligned rows, and 3-D scratches all still fire."""
+    """The (rows, 1) column exemption must not leak: misaligned rows and
+    3-D blocks or scratches with a trailing 1 all still fire."""
     res = lint_fixture("rl004_scalar_bad.py", select=["RL004"])
     col_hits = [f for f in res.findings if "last dim is 1" in f.message]
     assert len(col_hits) >= 2, res.format_human()
@@ -202,8 +202,8 @@ def test_real_tree_is_clean():
     report = json.loads(proc.stdout)
     assert report["findings"] == []
     # the deliberate exceptions are visible, not invisible (the RL004
-    # scalar-accumulator scratches are codified in the rule now, so only
-    # the RL001 replicated-loss exceptions remain suppressed)
+    # per-row columns are codified in the rule, so only the RL001
+    # replicated-loss exceptions remain suppressed)
     assert len(report["suppressed"]) >= 2
 
 

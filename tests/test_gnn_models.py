@@ -106,6 +106,32 @@ def test_minibatch_training_learns(sbm_graph):
     assert last < first
 
 
+def test_neighbor_sampler_shapes_fixed_and_step_compiles_once(sbm_graph):
+    """Block shapes depend only on (batch, fanouts), so ten batches share
+    one compiled training step."""
+    cfg = GNNConfig(arch="sage", feat_dim=16, hidden=32, num_classes=4)
+    params = GM.init_gnn(cfg, jax.random.PRNGKey(0))
+    opt = AdamW(lr=1e-2, weight_decay=0.0)
+    ostate = opt.init(params)
+    sampler = S.NeighborSampler(sbm_graph, [5, 5], seed=0)
+    step = jax.jit(GM.make_minibatch_train_step(cfg, opt))
+    rng = np.random.default_rng(0)
+    shapes = set()
+    for _ in range(10):
+        seeds = rng.choice(sbm_graph.num_nodes, 32, replace=False)
+        mb = sampler.sample(seeds)
+        shapes.add(tuple((b.num_dst, b.num_src, len(b.edge_src))
+                         for b in mb.blocks))
+        blocks = [DeviceGraph.from_block(b) for b in mb.blocks]
+        x_in = jnp.asarray(
+            sbm_graph.features[np.maximum(mb.blocks[0].src_nodes, 0)])
+        y = jnp.asarray(sbm_graph.labels[seeds])
+        params, ostate, _ = step(params, ostate, blocks, x_in, y,
+                                 jnp.ones_like(y, jnp.float32))
+    assert shapes == {((32 * 6, 32 * 36, 32 * 30), (32, 32 * 6, 32 * 5))}
+    assert step._cache_size() == 1
+
+
 @pytest.mark.parametrize("arch", ["ggnn", "appnp"])
 def test_new_archs_learn(sbm_graph, arch):
     cfg = GNNConfig(arch=arch, feat_dim=16, hidden=32, num_classes=4)
