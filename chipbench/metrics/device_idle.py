@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device, in %.
+Serves ``device_idle.<cell kind>``."""
+
+
+def read(run):
+    t = run.trace
+    if t["planes"] == 0 or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
