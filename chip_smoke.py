@@ -183,8 +183,6 @@ def train_run(argv) -> dict:
         "compiles_after_first_step": sum(1 for t, _ in compiles
                                          if t > first_end),
         "steps_per_s_after_first": (len(steps) - 1) / (t_end - first_end),
-        "dispatch": reg.snapshot().get("kernel_dispatch_total",
-                                       {}).get("series", {}),
     }
     stats = jax.devices()[0].memory_stats() or {}
     run["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")

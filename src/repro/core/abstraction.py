@@ -16,12 +16,14 @@ path) or the Pallas-blocked `repro.kernels.segment_sum` kernel.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.comm import QuantizedRows
 from repro.core.sampling import Block
 from repro.graph.structure import Graph
@@ -54,16 +56,25 @@ class DeviceGraph:
 
     @staticmethod
     def from_block(b: Block) -> "DeviceGraph":
-        es = jnp.asarray(b.edge_src, jnp.int32)
-        ed = jnp.asarray(b.edge_dst, jnp.int32)
-        m = jnp.asarray(b.edge_mask)
-        indeg = jnp.zeros((b.num_dst,), jnp.float32).at[ed].add(
-            m.astype(jnp.float32))
-        indeg = jnp.maximum(indeg, 1.0)
-        outdeg = jnp.zeros((b.num_src,), jnp.float32).at[es].add(
-            m.astype(jnp.float32))
-        return DeviceGraph(es, ed, m, b.num_src, b.num_dst, indeg,
-                           jnp.maximum(outdeg, 1.0))
+        """Upload a sampled block; its masked degrees are counted on the
+        device by one jitted call (span ``graph.from_block``)."""
+        with telemetry.span("graph.from_block"):
+            es = jnp.asarray(b.edge_src, jnp.int32)
+            ed = jnp.asarray(b.edge_dst, jnp.int32)
+            m = jnp.asarray(b.edge_mask)
+            indeg, outdeg = _block_degrees(ed, es, m, b.num_dst, b.num_src)
+            return DeviceGraph(es, ed, m, b.num_src, b.num_dst, indeg,
+                               outdeg)
+
+
+@functools.partial(jax.jit, static_argnames=("num_dst", "num_src"))
+def _block_degrees(edge_dst, edge_src, mask, num_dst: int, num_src: int):
+    """Masked in- and out-degrees of a block, at least 1."""
+    with jax.named_scope("graph.degrees"):
+        m = mask.astype(jnp.float32)
+        indeg = jnp.zeros((num_dst,), jnp.float32).at[edge_dst].add(m)
+        outdeg = jnp.zeros((num_src,), jnp.float32).at[edge_src].add(m)
+        return jnp.maximum(indeg, 1.0), jnp.maximum(outdeg, 1.0)
 
 
 jax.tree_util.register_dataclass(
@@ -78,14 +89,17 @@ jax.tree_util.register_dataclass(
 
 def segment_sum(msgs, seg_ids, num_segments, *, use_kernel: bool = False):
     """Gather-step segment reduction: ``jax.ops.segment_sum`` oracle or
-    the differentiable blocked Pallas kernel (``use_kernel=True``)."""
-    if use_kernel:
-        from repro.kernels import ops as kops
-        if msgs.ndim == 1:          # e.g. per-edge scalars/logits
-            return kops.segment_sum(msgs[:, None], seg_ids,
-                                    num_segments)[:, 0]
-        return kops.segment_sum(msgs, seg_ids, num_segments)
-    return jax.ops.segment_sum(msgs, seg_ids, num_segments)
+    the differentiable blocked Pallas kernel (``use_kernel=True``).
+    Scope ``gnn.aggregate``, with the implementation under it."""
+    with jax.named_scope("gnn.aggregate"):
+        if use_kernel:
+            from repro.kernels import ops as kops
+            if msgs.ndim == 1:          # e.g. per-edge scalars/logits
+                return kops.segment_sum(msgs[:, None], seg_ids,
+                                        num_segments)[:, 0]
+            return kops.segment_sum(msgs, seg_ids, num_segments)
+        with jax.named_scope("jax_ops"):
+            return jax.ops.segment_sum(msgs, seg_ids, num_segments)
 
 
 def gather_scale_segment_sum(h, edge_src, edge_dst, coef, num_dst, *,
@@ -97,24 +111,29 @@ def gather_scale_segment_sum(h, edge_src, edge_dst, coef, num_dst, *,
     (masked/pad edges carry 0).  With ``use_kernel=True`` this runs as
     ONE Pallas kernel that never materializes the (E, F) message tensor
     in HBM (see :mod:`repro.kernels.segment_sum`); the reference path
-    spells out the same computation in XLA ops.
+    spells out the same computation in XLA ops.  Scope
+    ``gnn.aggregate``, with the implementation under it (``jax_ops``, or
+    the kernel wrapper's own).
     """
-    if isinstance(h, QuantizedRows):
-        # int8-in path: wire-format rows aggregate without a decode
-        # round-trip on the kernel path; the reference path decodes
-        # first (same math the kernel performs per source slab)
+    with jax.named_scope("gnn.aggregate"):
+        if isinstance(h, QuantizedRows):
+            # int8-in path: wire-format rows aggregate without a decode
+            # round-trip on the kernel path; the reference path decodes
+            # first (same math the kernel performs per source slab)
+            if use_kernel:
+                from repro.kernels import ops as kops
+                return kops.gather_scale_segment_sum_q(
+                    jnp.asarray(h.q), jnp.asarray(h.mn),
+                    jnp.asarray(h.scale), edge_src, edge_dst, coef,
+                    num_dst)
+            h = jnp.asarray(h.dequantize())
         if use_kernel:
             from repro.kernels import ops as kops
-            return kops.gather_scale_segment_sum_q(
-                jnp.asarray(h.q), jnp.asarray(h.mn),
-                jnp.asarray(h.scale), edge_src, edge_dst, coef, num_dst)
-        h = jnp.asarray(h.dequantize())
-    if use_kernel:
-        from repro.kernels import ops as kops
-        return kops.gather_scale_segment_sum(h, edge_src, edge_dst,
-                                             coef, num_dst)
-    msgs = jnp.take(h, edge_src, axis=0) * coef[:, None]
-    return jax.ops.segment_sum(msgs, edge_dst, num_dst)
+            return kops.gather_scale_segment_sum(h, edge_src, edge_dst,
+                                                 coef, num_dst)
+        with jax.named_scope("jax_ops"):
+            msgs = jnp.take(h, edge_src, axis=0) * coef[:, None]
+            return jax.ops.segment_sum(msgs, edge_dst, num_dst)
 
 
 def segment_mean(msgs, seg_ids, num_segments, deg, *,

@@ -211,27 +211,37 @@ class FeatureStore:
         actually required (the rest return zero rows, keeping the batch
         shape static).  Only needed non-local rows count toward traffic,
         and a call whose mask selects no rows (or only local/cache hits)
-        issues no remote request — it adds 0 bytes, not a header."""
-        ids = np.asarray(ids)
-        needed = np.asarray(needed, bool) & (ids >= 0)
-        safe = np.maximum(ids, 0)
-        remote = needed & ~self._local_rows_mask(safe, needed)
-        hit = self.cached[safe] & remote
-        self.hits += int(hit.sum())
-        self._m_hits.inc(int(hit.sum()))
-        miss = remote & ~hit
-        miss_rows = int(miss.sum())
-        self.misses += miss_rows
-        self._m_misses.inc(miss_rows)
-        if self.g.features is None:
-            if miss_rows:
-                self.transport.account_opaque(miss_rows, 4)
-            return safe
-        out = np.zeros((len(ids), self.g.features.shape[1]),
-                       self.g.features.dtype)
-        out[needed] = self.g.features[safe[needed]]
-        if miss_rows:
-            out[miss] = self._pull_remote(out[miss], safe[miss])
+        issues no remote request — it adds 0 bytes, not a header.
+
+        Span ``store.fetch_masked``: ``rows`` (slots), ``pad_rows``
+        (slots returned as zero rows) and ``bytes`` (of the returned
+        array)."""
+        with telemetry.span("store.fetch_masked") as attrs:
+            ids = np.asarray(ids)
+            needed = np.asarray(needed, bool) & (ids >= 0)
+            safe = np.maximum(ids, 0)
+            remote = needed & ~self._local_rows_mask(safe, needed)
+            hit = self.cached[safe] & remote
+            self.hits += int(hit.sum())
+            self._m_hits.inc(int(hit.sum()))
+            miss = remote & ~hit
+            miss_rows = int(miss.sum())
+            self.misses += miss_rows
+            self._m_misses.inc(miss_rows)
+            if self.g.features is None:
+                if miss_rows:
+                    self.transport.account_opaque(miss_rows, 4)
+                out = safe
+            else:
+                out = np.zeros((len(ids), self.g.features.shape[1]),
+                               self.g.features.dtype)
+                out[needed] = self.g.features[safe[needed]]
+                if miss_rows:
+                    out[miss] = self._pull_remote(out[miss], safe[miss])
+            if attrs is not None:
+                attrs.update(rows=len(ids),
+                             pad_rows=len(ids) - int(needed.sum()),
+                             bytes=out.nbytes)
         return out
 
     def fetch_masked_wire(self, ids: np.ndarray,

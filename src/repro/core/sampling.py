@@ -20,6 +20,7 @@ from typing import List, Sequence
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.graph.structure import Graph
 
 
@@ -164,18 +165,20 @@ class NeighborSampler:
         self.rng = np.random.default_rng(seed)
 
     def sample(self, seeds: np.ndarray) -> MiniBatch:
-        seeds = np.asarray(seeds, np.int64)
-        blocks: List[Block] = []
-        dst = seeds
-        for f in reversed(self.fanouts):
-            def pick(d, nbr, f=f):
-                return nbr if len(nbr) <= f else self.rng.choice(
-                    nbr, f, replace=False)
-            blocks.append(sample_block_padded(self.g, self.gr, dst, f, None,
-                                              picker=pick))
-            dst = blocks[-1].src_nodes
-        blocks.reverse()
-        return MiniBatch(blocks, seeds, blocks[0].src_nodes)
+        """One mini-batch for ``seeds`` (span ``sampler.sample``)."""
+        with telemetry.span("sampler.sample"):
+            seeds = np.asarray(seeds, np.int64)
+            blocks: List[Block] = []
+            dst = seeds
+            for f in reversed(self.fanouts):
+                def pick(d, nbr, f=f):
+                    return nbr if len(nbr) <= f else self.rng.choice(
+                        nbr, f, replace=False)
+                blocks.append(sample_block_padded(self.g, self.gr, dst, f,
+                                                  None, picker=pick))
+                dst = blocks[-1].src_nodes
+            blocks.reverse()
+            return MiniBatch(blocks, seeds, blocks[0].src_nodes)
 
 
 # ===========================================================================

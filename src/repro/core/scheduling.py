@@ -19,6 +19,8 @@ from typing import Callable, Iterable, List, Sequence
 
 import numpy as np
 
+from repro.core import telemetry
+
 
 class PipelinedLoader:
     """Prefetching iterator: ``sample_fn()`` runs in ``n_workers`` threads
@@ -50,9 +52,15 @@ class PipelinedLoader:
         return self
 
     def __next__(self):
-        t0 = time.perf_counter()
-        item = self.q.get()
-        self.idle_s += time.perf_counter() - t0
+        """The next sampled item, waiting for a worker if none is ready.
+        Span ``loader.get``, with ``queue`` ``empty`` or ``ready``:
+        whether an item was waiting when the consumer asked."""
+        with telemetry.span("loader.get") as attrs:
+            if attrs is not None:
+                attrs["queue"] = "empty" if self.q.empty() else "ready"
+            t0 = time.perf_counter()
+            item = self.q.get()
+            self.idle_s += time.perf_counter() - t0
         return item
 
     def close(self):

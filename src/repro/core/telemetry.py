@@ -21,8 +21,11 @@ numbers flow through:
 * :class:`Tracer` — a lightweight span tracer:
   ``with span("serve.batch"):`` nests via a thread-local stack and each
   span may carry its own clock (``clock=``), which is how serving's
-  *virtual* clock produces spans in simulated time.  Export is JSONL,
-  one event per line (schema in ``docs/observability.md``).
+  *virtual* clock produces spans in simulated time.  Each span records
+  its thread's CPU seconds beside its wall duration, and, while a JAX
+  profiler trace is being recorded, writes itself into that trace as
+  ``repro.<name>``, on the clock of the device operations.  Export is
+  JSONL, one event per line (schema in ``docs/observability.md``).
 * :meth:`MetricsRegistry.to_prometheus` — Prometheus text-format
   exposition (``# HELP`` / ``# TYPE`` + cumulative ``_bucket``/``_sum``/
   ``_count`` series); :func:`parse_prometheus` is the matching
@@ -38,7 +41,9 @@ counters), caching (:class:`~repro.core.caching.FeatureStore` and
 histogram, staleness-violation guard), serving
 (:class:`~repro.serving.server.GNNInferenceServer` queue depth, batch
 occupancy, latency histograms, virtual-clock spans), training step-time
-histograms and prefetcher stall time, and kernel dispatch counters
+histograms and prefetcher stall time, the mini-batch input path's spans
+(``loader.get``, ``sampler.sample``, ``store.fetch_masked``,
+``graph.from_block``), and the blocked kernels' tile density
 (:mod:`repro.kernels.ops`).  Enable with ``--metrics-out`` /
 ``--trace-out`` on ``launch/{train_gnn,serve_gnn}.py`` or
 :func:`set_enabled`.
@@ -48,6 +53,7 @@ from __future__ import annotations
 import json
 import math
 import re
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -297,12 +303,17 @@ class SpanError(RuntimeError):
 class Tracer:
     """Nesting span tracer with pluggable clocks and JSONL export.
 
-    ``with tracer.span("serve.batch", bucket=16):`` records one event on
-    exit: ``{seq, name, ts, dur, depth, parent, attrs}`` where ``ts`` is
-    the span's start on its clock, ``depth`` the nesting level (0 = root)
-    and ``parent`` the enclosing span's name (``None`` at the root).  The
-    stack is thread-local, so prefetcher-thread spans nest independently
-    of the main thread's.
+    ``with tracer.span("serve.batch", bucket=16) as attrs:`` records one
+    event on exit: ``{seq, name, ts, dur, cpu, depth, parent, attrs}``
+    where ``ts`` is the span's start on its clock, ``cpu`` the thread's
+    CPU seconds over the span (``time.thread_time``; wall minus CPU is
+    time the thread was ready but not running, for this repo's host
+    threads mostly waiting for the GIL), ``depth`` the nesting level
+    (0 = root) and ``parent`` the enclosing span's name (``None`` at the
+    root).  ``attrs`` is the dict the span yields: the body may add
+    attributes it only learns inside the span.  The stack is
+    thread-local, so loader-thread spans nest independently of the main
+    thread's.
 
     Clocks: the default is ``time.perf_counter`` (wall).  A span may
     override with ``clock=``, which is how serving traces in *virtual*
@@ -311,9 +322,15 @@ class Tracer:
     axis as the reported p50/p99 (see
     ``GNNInferenceServer._virtual_now``).
 
-    Recording is gated on the owning registry's enable flag (one branch
-    per span); a disabled tracer's ``span`` still yields, costing only
-    the context-manager machinery.
+    While a JAX profiler trace is being recorded, a span on the wall
+    clock also writes itself into that trace as ``repro.<name>`` (a
+    ``jax.profiler.TraceAnnotation``), with its attributes and ``cpu``,
+    so program spans and device operations share one clock.  A
+    virtual-clock span gets no annotation.
+
+    Recording is gated on the owning registry's enable flag and the
+    profiler's: with neither on, ``span`` yields ``None`` after one
+    branch and one check of the profiler, and records nothing.
     """
 
     def __init__(self, registry: Optional["MetricsRegistry"] = None,
@@ -322,6 +339,7 @@ class Tracer:
         self.clock = clock
         self.events: List[dict] = []
         self._local = threading.local()
+        self._lock = threading.Lock()
         self._seq = 0
 
     @property
@@ -338,30 +356,42 @@ class Tracer:
     def span(self, name: str, clock: Optional[Callable[[], float]] = None,
              **attrs):
         """Context manager recording one span event on exit (see class
-        docstring for the event schema)."""
-        if not self._on:
-            yield
+        docstring for the event schema); yields the span's attribute
+        dict, or ``None`` when nothing records."""
+        record = self._on
+        annotation = _profiler_annotation(name) if clock is None else None
+        if not (record or annotation):
+            yield None
             return
         clk = clock or self.clock
         stack = self._stack()
         parent = stack[-1] if stack else None
         depth = len(stack)
         stack.append(name)
+        attrs = dict(attrs)
+        if annotation:
+            annotation.__enter__()
+        c0 = time.thread_time()
         t0 = clk()
         try:
-            yield
+            yield attrs
         finally:
             dur = clk() - t0
+            cpu = time.thread_time() - c0
+            if annotation:
+                annotation.set_metadata(cpu=cpu, **attrs)
+                annotation.__exit__(None, None, None)
             popped = stack.pop()
             if popped != name:
                 raise SpanError(f"span stack corrupted: popped {popped!r}, "
                                 f"expected {name!r}")
-            self.events.append({
-                "seq": self._seq, "name": name, "ts": t0, "dur": dur,
-                "depth": depth, "parent": parent,
-                "attrs": {k: v for k, v in attrs.items()},
-            })
-            self._seq += 1
+            if record:
+                with self._lock:
+                    self.events.append({
+                        "seq": self._seq, "name": name, "ts": t0,
+                        "dur": dur, "cpu": cpu, "depth": depth,
+                        "parent": parent, "attrs": attrs})
+                    self._seq += 1
 
     def export_jsonl(self, path: str) -> int:
         """Write one JSON object per event line; returns the event count."""
@@ -373,8 +403,19 @@ class Tracer:
     def reset(self) -> None:
         """Drop recorded events (the per-thread stacks survive — resetting
         mid-span keeps nesting coherent for later events)."""
-        self.events = []
-        self._seq = 0
+        with self._lock:
+            self.events = []
+            self._seq = 0
+
+
+def _profiler_annotation(name: str):
+    """A ``repro.<name>`` profiler annotation while a JAX profiler trace
+    is being recorded, else ``None``.  A process that never imported JAX
+    records no trace, so JAX is looked up, never imported, here."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax.profiler.TraceAnnotation.is_enabled():
+        return None
+    return jax.profiler.TraceAnnotation("repro." + name)
 
 
 class MetricsRegistry:
@@ -636,14 +677,14 @@ def validate_trace_jsonl(path: str) -> int:
     with open(path, encoding="utf-8") as f:
         for i, line in enumerate(f):
             ev = json.loads(line)
-            for k in ("seq", "name", "ts", "dur", "depth", "parent",
+            for k in ("seq", "name", "ts", "dur", "cpu", "depth", "parent",
                       "attrs"):
                 if k not in ev:
                     raise ValueError(f"event {i}: missing key {k!r}")
             if ev["seq"] != i:
                 raise ValueError(f"event {i}: seq {ev['seq']} not dense")
-            if ev["dur"] < 0 or ev["depth"] < 0:
-                raise ValueError(f"event {i}: negative dur/depth")
+            if ev["dur"] < 0 or ev["cpu"] < 0 or ev["depth"] < 0:
+                raise ValueError(f"event {i}: negative dur/cpu/depth")
             if ev["depth"] == 0 and ev["parent"] is not None:
                 raise ValueError(f"event {i}: root span with parent")
             if ev["depth"] > 0 and ev["parent"] is None:

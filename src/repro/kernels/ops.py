@@ -32,40 +32,6 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-# Dispatch counters.  The wrapper bodies below run when Python calls them
-# — eagerly, or ONCE per shape at trace time when embedded in an outer
-# ``jit`` — so these count *dispatch decisions* (which implementation the
-# capacity check selected for a shape), not per-step kernel launches.
-_m_dispatch_ss = telemetry.counter(
-    "kernel_dispatch_total", "kernel wrapper dispatch decisions "
-    "(trace-time inside jit)", kernel="segment_sum", impl="blocked")
-_m_dispatch_fused = telemetry.counter(
-    "kernel_dispatch_total", kernel="gather_scale_segment_sum",
-    impl="fused")
-_m_dispatch_unfused = telemetry.counter(
-    "kernel_dispatch_total", kernel="gather_scale_segment_sum",
-    impl="unfused_fallback")
-# Modeled HBM traffic (total fwd+bwd bytes) of the most recent dispatch,
-# from the analytic models in :mod:`repro.kernels.segment_sum`
-_m_hbm_fused = telemetry.gauge(
-    "kernel_hbm_model_bytes", "modeled HBM bytes (fwd+bwd) of the latest "
-    "dispatched shape", kernel="gather_scale_segment_sum", impl="fused")
-_m_hbm_unfused = telemetry.gauge(
-    "kernel_hbm_model_bytes", kernel="gather_scale_segment_sum",
-    impl="unfused_fallback")
-_m_dispatch_gat_fused = telemetry.counter(
-    "kernel_dispatch_total", kernel="gat_attention", impl="fused_one_pass")
-_m_dispatch_gat_multipass = telemetry.counter(
-    "kernel_dispatch_total", kernel="gat_attention",
-    impl="multipass_fallback")
-_m_dispatch_q = telemetry.counter(
-    "kernel_dispatch_total", kernel="gather_scale_segment_sum",
-    impl="fused_int8_in")
-_m_hbm_gat_fused = telemetry.gauge(
-    "kernel_hbm_model_bytes", kernel="gat_attention", impl="fused_one_pass")
-_m_hbm_gat_multipass = telemetry.gauge(
-    "kernel_hbm_model_bytes", kernel="gat_attention",
-    impl="multipass_fallback")
 # VMEM-residency / tile-density of the most recently recorded edge
 # ordering (host-side: launchers and benches call record_tile_density;
 # edge ids are tracers inside jit, so the wrappers cannot)
@@ -96,9 +62,9 @@ def _segment_sum_jit(msgs, seg_ids, num_segments: int, interpret: bool):
 def segment_sum(msgs, seg_ids, num_segments: int):
     """Differentiable blocked segment-sum (scatter-add); the VJP is a
     blocked gather kernel.  See :mod:`repro.kernels.segment_sum`."""
-    _m_dispatch_ss.inc()
-    return _segment_sum_jit(msgs, seg_ids, num_segments,
-                            interpret=_interpret())
+    with jax.named_scope("pallas_unfused"):
+        return _segment_sum_jit(msgs, seg_ids, num_segments,
+                                interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("num_dst", "interpret"))
@@ -135,7 +101,6 @@ def gather_scale_segment_sum(h, edge_src, edge_dst, coef, num_dst: int):
     so ``use_kernel=True`` never hits the VMEM assert from this path.
     """
     S, F = h.shape
-    E = len(edge_src)
     interpret = _interpret()
     if not _ss.fused_fits(S, num_dst, F):
         key = (S, num_dst, F)
@@ -146,15 +111,12 @@ def gather_scale_segment_sum(h, edge_src, edge_dst, coef, num_dst: int):
                 f"num_src={S}, num_dst={num_dst}, F={F} exceeds the "
                 f"budget; dispatching to the unfused blocked kernel "
                 f"(the (E, F) message tensor WILL cross HBM)")
-        _m_dispatch_unfused.inc()
-        _m_hbm_unfused.set(
-            _ss.hbm_bytes_unfused_kernel(E, F, num_dst)["total"])
-        return _gss_unfused_jit(h, edge_src, edge_dst, coef, num_dst,
-                                interpret=interpret)
-    _m_dispatch_fused.inc()
-    _m_hbm_fused.set(_ss.hbm_bytes_fused_kernel(E, F, num_dst, S)["total"])
-    return _gss_jit(h, edge_src, edge_dst, coef, num_dst,
-                    interpret=interpret)
+        with jax.named_scope("pallas_unfused"):
+            return _gss_unfused_jit(h, edge_src, edge_dst, coef, num_dst,
+                                    interpret=interpret)
+    with jax.named_scope("pallas_fused"):
+        return _gss_jit(h, edge_src, edge_dst, coef, num_dst,
+                        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("num_dst", "interpret"))
@@ -177,20 +139,15 @@ def gather_scale_segment_sum_q(q, mn, scale, edge_src, edge_dst, coef,
     blocked scatter kernel (correctness identical — the decode
     round-trip saving is a fits-only optimization)."""
     S, F = q.shape
-    E = len(edge_src)
     interpret = _interpret()
     if not _ss.fused_fits(S, num_dst, F):
-        _m_dispatch_unfused.inc()
-        _m_hbm_unfused.set(
-            _ss.hbm_bytes_unfused_kernel(E, F, num_dst)["total"])
-        h = (mn + q.astype(jnp.float32) * scale).astype(jnp.float32)
-        return _gss_unfused_jit(h, edge_src, edge_dst, coef, num_dst,
-                                interpret=interpret)
-    _m_dispatch_q.inc()
-    _m_hbm_fused.set(
-        _ss.hbm_bytes_fused_q_kernel(E, F, num_dst, S)["fwd"])
-    return _gss_q_jit(q, mn, scale, edge_src, edge_dst, coef, num_dst,
-                      interpret=interpret)
+        with jax.named_scope("pallas_unfused"):
+            h = (mn + q.astype(jnp.float32) * scale).astype(jnp.float32)
+            return _gss_unfused_jit(h, edge_src, edge_dst, coef, num_dst,
+                                    interpret=interpret)
+    with jax.named_scope("pallas_fused"):
+        return _gss_q_jit(q, mn, scale, edge_src, edge_dst, coef, num_dst,
+                          interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -249,7 +206,6 @@ def gat_attention(hs, es, ed, edge_src, edge_dst, mask, num_dst: int, *,
     source slabs exceed the VMEM budget the multi-pass kernel path runs
     instead, so ``use_kernel=True`` GAT never hits the VMEM assert."""
     S = hs.shape[0]
-    E = len(edge_src)
     hd = hs.shape[1] // heads
     interpret = _interpret()
     if not _gat.gat_fused_fits(S, num_dst, heads, hd):
@@ -261,17 +217,12 @@ def gat_attention(hs, es, ed, edge_src, edge_dst, mask, num_dst: int, *,
                 f"num_src={S}, num_dst={num_dst}, heads={heads}, hd={hd} "
                 f"exceeds the budget; dispatching to the multi-pass "
                 f"kernel path (edge logits/alphas WILL cross HBM)")
-        _m_dispatch_gat_multipass.inc()
-        _m_hbm_gat_multipass.set(
-            _gat.hbm_bytes_gat_multipass(E, heads, hd, num_dst,
-                                         S)["total"])
-        return _gat_multipass_jit(hs, es, ed, edge_src, edge_dst, mask,
-                                  num_dst, heads, interpret=interpret)
-    _m_dispatch_gat_fused.inc()
-    _m_hbm_gat_fused.set(
-        _gat.hbm_bytes_gat_fused(E, heads, hd, num_dst, S)["total"])
-    return _gat_fused_jit(hs, es, ed, edge_src, edge_dst, mask, num_dst,
-                          heads, interpret=interpret)
+        with jax.named_scope("gat_multipass"):
+            return _gat_multipass_jit(hs, es, ed, edge_src, edge_dst, mask,
+                                      num_dst, heads, interpret=interpret)
+    with jax.named_scope("gat_fused"):
+        return _gat_fused_jit(hs, es, ed, edge_src, edge_dst, mask,
+                              num_dst, heads, interpret=interpret)
 
 
 @functools.partial(jax.jit,

@@ -37,6 +37,13 @@ import sys
 import time
 
 
+# A dispatch returns before its device work ends, so timing one dispatch
+# times host preparation; the interval between successive returns is the
+# step period in steady state (n steps give n - 1 samples)
+STEP_SECONDS_HELP = ("interval between successive step dispatch returns "
+                     "(the step period in steady state)")
+
+
 def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=1)
@@ -383,15 +390,17 @@ def run(args):
         steps_per_epoch = max(1, g.num_nodes // args.batch)
         loss = None
         m_step = telemetry.histogram(
-            "train_step_seconds", "wall time per executed training step",
-            mode="minibatch_dist")
+            "train_step_seconds", STEP_SECONDS_HELP, mode="minibatch_dist")
+        last = None
         for epoch in range(args.epochs):
             for _ in range(steps_per_epoch):
                 arrays = next(prefetch)
-                t0 = time.perf_counter()
                 with telemetry.span("train.step", mode="minibatch_dist"):
                     params, ostate, loss = dstep(params, ostate, arrays)
-                m_step.observe(time.perf_counter() - t0)
+                now = time.perf_counter()
+                if last is not None:
+                    m_step.observe(now - last)
+                last = now
             # monitoring only: the ratio also covers the 1-2 batches the
             # prefetcher sampled ahead; exact byte totals come after close
             st = dsampler.stats()
@@ -434,8 +443,8 @@ def run(args):
     steps_per_epoch = max(1, g.num_nodes // args.batch)
     loss = None
     m_step = telemetry.histogram(
-        "train_step_seconds", "wall time per executed training step",
-        mode="minibatch_single")
+        "train_step_seconds", STEP_SECONDS_HELP, mode="minibatch_single")
+    last = None
     for epoch in range(args.epochs):
         for i in range(steps_per_epoch):
             mb, seeds = next(loader)
@@ -457,7 +466,10 @@ def run(args):
                 y = jnp.asarray(g.labels[seeds])
                 params, ostate, loss = step(params, ostate, blocks, x_in,
                                             y, jnp.ones_like(y, jnp.float32))
-            m_step.observe(time.perf_counter() - t0)
+            now = time.perf_counter()
+            if last is not None:
+                m_step.observe(now - last)
+            last = now
             if epoch == 0 and i == 0:
                 print(f"step 0 loss {float(loss):.4f} "
                       f"({time.perf_counter() - t0:.1f} s, compile "

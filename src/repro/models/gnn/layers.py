@@ -1,5 +1,11 @@
 """GNN layers built on the SAGA-NN / message-passing abstraction
 (survey Table 5 algorithms: GCN, GraphSAGE, GAT, GIN).
+
+Each layer names its device work with ``jax.named_scope``: ``gnn.dense``
+for projections and updates, ``gnn.norm`` for degree coefficients and
+normalisation, ``gnn.aggregate`` (set by the aggregation itself) for the
+gather and reduction.  The scopes reach the compiled program's
+``op_name`` metadata, under ``transpose(jvp(...))`` in the backward pass.
 """
 from __future__ import annotations
 
@@ -32,18 +38,19 @@ class GCNLayer(MessagePassing):
                  use_kernel=False):
         if isinstance(x_src, QuantizedRows):
             x_src = jnp.asarray(x_src.dequantize())   # projects first
-        if x_dst is None:
-            x_dst = x_src[:g.num_dst]
-        h = x_src @ p["w"]
-        norm_src = jax.lax.rsqrt(g.out_deg)
-        norm_dst = jax.lax.rsqrt(g.in_deg)
-        coef = jnp.take(norm_src, g.edge_src) * jnp.take(norm_dst, g.edge_dst)
+        with jax.named_scope("gnn.dense"):
+            h = x_src @ p["w"]
+        with jax.named_scope("gnn.norm"):
+            norm_src = jax.lax.rsqrt(g.out_deg)
+            norm_dst = jax.lax.rsqrt(g.in_deg)
+            coef = (jnp.take(norm_src, g.edge_src)
+                    * jnp.take(norm_dst, g.edge_dst) * g.edge_mask)
         # fused gather+scale+reduce: the (E, F) message tensor only ever
         # exists tile-by-tile in VMEM on the kernel path
-        agg = gather_scale_segment_sum(h, g.edge_src, g.edge_dst,
-                                       coef * g.edge_mask, g.num_dst,
-                                       use_kernel=use_kernel)
-        return agg + p["b"]
+        agg = gather_scale_segment_sum(h, g.edge_src, g.edge_dst, coef,
+                                       g.num_dst, use_kernel=use_kernel)
+        with jax.named_scope("gnn.dense"):
+            return agg + p["b"]
 
 
 class SAGELayer(MessagePassing):
@@ -69,22 +76,26 @@ class SAGELayer(MessagePassing):
                 "b": jnp.zeros((dout,), jnp.float32)}
 
     def update(self, p, agg, self_feat):
-        return self_feat @ p["w_self"] + agg @ p["w_nbr"] + p["b"]
+        with jax.named_scope("gnn.dense"):
+            return self_feat @ p["w_self"] + agg @ p["w_nbr"] + p["b"]
 
     def __call__(self, p, g: DeviceGraph, x_src, x_dst=None, *,
                  use_kernel=False):
         if x_dst is None:
             # the self path needs fp32 rows; only the num_dst prefix
             # is ever dequantized host-side on the int8-in path
-            x_dst = (jnp.asarray(
-                x_src.rows(slice(0, g.num_dst)).dequantize())
-                if isinstance(x_src, QuantizedRows)
-                else x_src[:g.num_dst])
-        coef = g.edge_mask.astype(jnp.float32)
+            with jax.named_scope("gnn.dense"):
+                x_dst = (jnp.asarray(
+                    x_src.rows(slice(0, g.num_dst)).dequantize())
+                    if isinstance(x_src, QuantizedRows)
+                    else x_src[:g.num_dst])
+        with jax.named_scope("gnn.norm"):
+            coef = g.edge_mask.astype(jnp.float32)
         agg = gather_scale_segment_sum(x_src, g.edge_src, g.edge_dst,
                                        coef, g.num_dst,
                                        use_kernel=use_kernel)
-        agg = agg / g.in_deg[:, None]
+        with jax.named_scope("gnn.norm"):
+            agg = agg / g.in_deg[:, None]
         return self.update(p, agg, x_dst)
 
 
@@ -111,27 +122,28 @@ class GATLayer(MessagePassing):
         if x_dst is None:
             x_dst = x_src[:g.num_dst]
         heads, hd = p["a_src"].shape
-        hs = (x_src @ p["w"]).reshape(-1, heads, hd)
-        hdst = (x_dst @ p["w"]).reshape(-1, heads, hd)
-        es = jnp.einsum("nhd,hd->nh", hs, p["a_src"])
-        ed = jnp.einsum("nhd,hd->nh", hdst, p["a_dst"])
-        if use_kernel:
-            # one-pass fused online-softmax kernel: edge logits and
-            # alphas never reach HBM (falls back to the multi-pass
-            # kernel path when the VMEM capacity predicate says no)
-            from repro.kernels import ops as kops
-            return kops.gat_attention(
-                hs.reshape(-1, heads * hd), es, ed, g.edge_src,
-                g.edge_dst, g.edge_mask, g.num_dst, heads=heads)
-        logits = jax.nn.leaky_relu(
-            jnp.take(es, g.edge_src, axis=0)
-            + jnp.take(ed, g.edge_dst, axis=0), 0.2)        # (E, heads)
-        alpha = segment_softmax(logits, g.edge_dst, g.num_dst, g.edge_mask,
-                                use_kernel=use_kernel)
-        msgs = jnp.take(hs, g.edge_src, axis=0) * alpha[..., None]
-        agg = segment_sum(msgs.reshape(-1, heads * hd), g.edge_dst,
-                          g.num_dst, use_kernel=use_kernel)
-        return agg
+        with jax.named_scope("gnn.dense"):
+            hs = (x_src @ p["w"]).reshape(-1, heads, hd)
+            hdst = (x_dst @ p["w"]).reshape(-1, heads, hd)
+            es = jnp.einsum("nhd,hd->nh", hs, p["a_src"])
+            ed = jnp.einsum("nhd,hd->nh", hdst, p["a_dst"])
+        with jax.named_scope("gnn.aggregate"):
+            if use_kernel:
+                # one-pass fused online-softmax kernel: edge logits and
+                # alphas never reach HBM (falls back to the multi-pass
+                # kernel path when the VMEM capacity predicate says no)
+                from repro.kernels import ops as kops
+                return kops.gat_attention(
+                    hs.reshape(-1, heads * hd), es, ed, g.edge_src,
+                    g.edge_dst, g.edge_mask, g.num_dst, heads=heads)
+            logits = jax.nn.leaky_relu(
+                jnp.take(es, g.edge_src, axis=0)
+                + jnp.take(ed, g.edge_dst, axis=0), 0.2)    # (E, heads)
+            alpha = segment_softmax(logits, g.edge_dst, g.num_dst,
+                                    g.edge_mask, use_kernel=use_kernel)
+            msgs = jnp.take(hs, g.edge_src, axis=0) * alpha[..., None]
+            return segment_sum(msgs.reshape(-1, heads * hd), g.edge_dst,
+                               g.num_dst, use_kernel=use_kernel)
 
 
 class GINLayer(MessagePassing):
@@ -149,9 +161,10 @@ class GINLayer(MessagePassing):
                 "eps": jnp.zeros((), jnp.float32)}
 
     def update(self, p, agg, self_feat):
-        h = (1.0 + p["eps"]) * self_feat + agg
-        h = jax.nn.relu(h @ p["w1"] + p["b1"])
-        return h @ p["w2"] + p["b2"]
+        with jax.named_scope("gnn.dense"):
+            h = (1.0 + p["eps"]) * self_feat + agg
+            h = jax.nn.relu(h @ p["w1"] + p["b1"])
+            return h @ p["w2"] + p["b2"]
 
 
 class GGNNLayer(MessagePassing):
@@ -173,23 +186,25 @@ class GGNNLayer(MessagePassing):
     def __call__(self, p, g, x_src, x_dst=None, *, use_kernel=False):
         if isinstance(x_src, QuantizedRows):
             x_src = jnp.asarray(x_src.dequantize())   # projects first
-        if p.get("proj") is not None:
-            x_src = x_src @ p["proj"]
-        if x_dst is None:
-            x_dst = x_src[:g.num_dst]
-        hm = x_src @ p["w_msg"]
+        with jax.named_scope("gnn.dense"):
+            if p.get("proj") is not None:
+                x_src = x_src @ p["proj"]
+            if x_dst is None:
+                x_dst = x_src[:g.num_dst]
+            hm = x_src @ p["w_msg"]
         agg = gather_scale_segment_sum(
             hm, g.edge_src, g.edge_dst,
             g.edge_mask.astype(hm.dtype), g.num_dst,
             use_kernel=use_kernel)
         d = x_dst.shape[-1]
-        gates = agg @ p["w_zrh"] + x_dst @ p["u_zrh"] + p["b"]
-        z = jax.nn.sigmoid(gates[:, :d])
-        r = jax.nn.sigmoid(gates[:, d:2 * d])
-        # candidate uses reset-gated state through the U path
-        h_tilde = jnp.tanh(agg @ p["w_zrh"][:, 2 * d:]
-                           + (r * x_dst) @ p["u_zrh"][:, 2 * d:])
-        return (1 - z) * x_dst + z * h_tilde
+        with jax.named_scope("gnn.dense"):
+            gates = agg @ p["w_zrh"] + x_dst @ p["u_zrh"] + p["b"]
+            z = jax.nn.sigmoid(gates[:, :d])
+            r = jax.nn.sigmoid(gates[:, d:2 * d])
+            # candidate uses reset-gated state through the U path
+            h_tilde = jnp.tanh(agg @ p["w_zrh"][:, 2 * d:]
+                               + (r * x_dst) @ p["u_zrh"][:, 2 * d:])
+            return (1 - z) * x_dst + z * h_tilde
 
 
 class APPNPLayer(MessagePassing):
@@ -206,11 +221,13 @@ class APPNPLayer(MessagePassing):
         return {"w": _dense(key, din, dout)}  # used only by the first hop
 
     def propagate(self, g, h, h0, *, use_kernel=False):
-        coef = (jax.lax.rsqrt(g.out_deg)[g.edge_src]
-                * jax.lax.rsqrt(g.in_deg)[g.edge_dst] * g.edge_mask)
+        with jax.named_scope("gnn.norm"):
+            coef = (jax.lax.rsqrt(g.out_deg)[g.edge_src]
+                    * jax.lax.rsqrt(g.in_deg)[g.edge_dst] * g.edge_mask)
         agg = gather_scale_segment_sum(h, g.edge_src, g.edge_dst, coef,
                                        g.num_dst, use_kernel=use_kernel)
-        return (1 - self.alpha) * agg + self.alpha * h0
+        with jax.named_scope("gnn.dense"):
+            return (1 - self.alpha) * agg + self.alpha * h0
 
 
 LAYER_TYPES = {"gcn": GCNLayer, "sage": SAGELayer, "gat": GATLayer,

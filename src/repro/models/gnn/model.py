@@ -61,7 +61,8 @@ def forward_full(cfg: GNNConfig, params, g: DeviceGraph, x) -> jax.Array:
     if cfg.arch == "appnp":
         from repro.models.gnn.layers import APPNPLayer
         layer = APPNPLayer(cfg.appnp_alpha)
-        h = jax.nn.relu(x @ params[0]["w"]) @ params[1]["w"]
+        with jax.named_scope("gnn.dense"):
+            h = jax.nn.relu(x @ params[0]["w"]) @ params[1]["w"]
         h0 = h
         for _ in range(cfg.appnp_k):
             h = layer.propagate(g, h, h0, use_kernel=cfg.use_kernel)
@@ -71,7 +72,8 @@ def forward_full(cfg: GNNConfig, params, g: DeviceGraph, x) -> jax.Array:
     for i, p in enumerate(params):
         h = layer(p, g, h, use_kernel=cfg.use_kernel)
         if i + 1 < len(params):
-            h = jax.nn.relu(h)
+            with jax.named_scope("gnn.dense"):
+                h = jax.nn.relu(h)
     return h
 
 
@@ -84,7 +86,8 @@ def forward_blocks(cfg: GNNConfig, params, blocks: Sequence[DeviceGraph],
     for i, (p, g) in enumerate(zip(params, blocks)):
         h = layer(p, g, h, use_kernel=cfg.use_kernel)
         if i + 1 < len(params):
-            h = jax.nn.relu(h)
+            with jax.named_scope("gnn.dense"):
+                h = jax.nn.relu(h)
     return h
 
 
@@ -181,13 +184,17 @@ def forward_stale(params, h_own, sg_local, ghosts, refresh, own_rows,
             planes.append(dec)           # wire view: what receivers store
             h_all = jnp.where(own_rows[:, None], h_all_fresh,
                               jnp.where(mask, dec, ghosts[i - 1]))
-        hw = h_all @ p["w"]
-        coef = (jax.lax.rsqrt(jnp.take(outdeg_all, es))
-                * jax.lax.rsqrt(jnp.take(indeg_l, ed)))
-        h = gather_scale_segment_sum(hw, es, ed, coef * em, n_local,
-                                     use_kernel=use_kernel) + p["b"]
-        if i + 1 < n_layers:
-            h = jax.nn.relu(h)
+        with jax.named_scope("gnn.dense"):
+            hw = h_all @ p["w"]
+        with jax.named_scope("gnn.norm"):
+            coef = (jax.lax.rsqrt(jnp.take(outdeg_all, es))
+                    * jax.lax.rsqrt(jnp.take(indeg_l, ed)) * em)
+        h = gather_scale_segment_sum(hw, es, ed, coef, n_local,
+                                     use_kernel=use_kernel)
+        with jax.named_scope("gnn.dense"):
+            h = h + p["b"]
+            if i + 1 < n_layers:
+                h = jax.nn.relu(h)
     return h, planes, tuple(res_out)
 
 
@@ -220,17 +227,19 @@ def nll_sum_count(logits, labels, mask):
     form a distributed step psums across partitions before dividing, so
     the global mean is identical to the single-device mean regardless of
     how seeds were split."""
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    nll = logz - gold
-    return jnp.sum(nll * mask), jnp.sum(mask)
+    with jax.named_scope("gnn.loss"):
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+        nll = logz - gold
+        return jnp.sum(nll * mask), jnp.sum(mask)
 
 
 def nll_loss(logits, labels, mask=None):
     if mask is None:
         mask = jnp.ones(labels.shape, logits.dtype)
     total, cnt = nll_sum_count(logits, labels, mask)
-    return total / jnp.maximum(cnt, 1.0)
+    with jax.named_scope("gnn.loss"):
+        return total / jnp.maximum(cnt, 1.0)
 
 
 def accuracy(logits, labels, mask=None):

@@ -41,6 +41,11 @@ class AdamW:
                 "step": jnp.zeros((), jnp.int32)}
 
     def apply(self, params, grads, state):
+        """One update (scope ``optimizer``)."""
+        with jax.named_scope("optimizer"):
+            return self._apply(params, grads, state)
+
+    def _apply(self, params, grads, state):
         step = state["step"] + 1
         lr = self.lr(step) if callable(self.lr) else self.lr
 
@@ -86,6 +91,11 @@ class Sgd:
             "step": jnp.zeros((), jnp.int32)}
 
     def apply(self, params, grads, state):
+        """One update (scope ``optimizer``)."""
+        with jax.named_scope("optimizer"):
+            return self._apply(params, grads, state)
+
+    def _apply(self, params, grads, state):
         step = state["step"] + 1
         lr = self.lr(step) if callable(self.lr) else self.lr
         if self.momentum:

@@ -8,8 +8,8 @@ hit/miss counters and per-path comm bytes must equal the
 Phase 2 (training): a 2-device ``--minibatch --wire-codec int8
 --use-kernel``-equivalent run; the snapshot must expose per-path comm
 bytes (matching the partition stores' ``Transport.total_bytes``), a
-step-time histogram with one sample per executed step, and nonzero
-kernel dispatch counts.
+step-time histogram with one sample per executed step, and a lowered step
+whose HLO names its aggregation's scope, forward and backward.
 
 Phase 3 (dynamic graphs): the update-log / invalidation counters
 (``graph_updates_total{kind}``, ``cache_invalidated_rows_total``,
@@ -133,11 +133,12 @@ assert int(mb_miss) == sum(s.misses for s in dist.stores)
 hs = snap["train_step_seconds"]["series"]["mode=minibatch_dist"]
 assert hs["count"] == STEPS, hs
 
-# kernel dispatch counters: use_kernel=True traced the fused aggregation
-kd = snap["kernel_dispatch_total"]["series"]
-fused = sum(v for k, v in kd.items()
-            if "kernel=gather_scale_segment_sum" in k)
-assert fused > 0, kd
+# named scopes: the lowered step names the aggregation's operations,
+# forward and (transposed) backward
+ir = jax.jit(dstep).lower(params, ostate, arrays).as_text(debug_info=True)
+scoped = ir.count("gnn.aggregate)/")
+assert "jvp(gnn.aggregate)/" in ir, "no gnn.aggregate scope in the step"
+assert "transpose(jvp(gnn.aggregate))/" in ir, "no backward scope"
 
 # ---------------------------------------------------------------------------
 # phase 3: dynamic-graph counters — registry == instance, reset in lockstep
@@ -204,5 +205,5 @@ with tempfile.TemporaryDirectory() as td:
 
 print(f"PASS telemetry-plane n_dev={N_DEV} "
       f"serve_hits={int(hits)} mb_kib={mb_bytes / 1024:.1f} "
-      f"steps={STEPS} fused_dispatch={int(fused)} events={n_ev} "
+      f"steps={STEPS} scoped_ops={scoped} events={n_ev} "
       f"dyn_invalidated={n_inv} dyn_ghost_rows={n_ghost_inv}")
