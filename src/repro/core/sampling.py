@@ -63,22 +63,32 @@ def _pad_to(a: np.ndarray, n: int, fill) -> np.ndarray:
     return out
 
 
+def _last_slot(keys: np.ndarray, values: np.ndarray) -> np.ndarray:
+    """Index of the last occurrence in ``keys`` of each of ``values``, -1
+    where a value does not occur (what a dict built by enumerating
+    ``keys`` would answer)."""
+    if len(keys) == 0:
+        return np.full(len(values), -1, np.int64)
+    order = np.argsort(keys, kind="stable")
+    ordered = keys[order]
+    pos = np.maximum(np.searchsorted(ordered, values, side="right") - 1, 0)
+    return np.where(ordered[pos] == values, order[pos], -1)
+
+
 def _build_block(g: Graph, dst: np.ndarray, src_extra: np.ndarray,
                  edges: np.ndarray, src_cap: int, edge_cap: int) -> Block:
-    """edges: (E,2) [src_global, dst_global]; src = dst ∪ extra (dst prefix)."""
+    """edges: (E,2) [src_global, dst_global]; src = dst ∪ extra (dst prefix).
+
+    Edges keep their order; an edge whose endpoint is not among the
+    (truncated) sources or the destinations is dropped."""
     src = np.concatenate([dst, np.setdiff1d(src_extra, dst)])
     src = src[:src_cap]
-    lookup_src = {v: i for i, v in enumerate(src)}
-    lookup_dst = {v: i for i, v in enumerate(dst)}
-    es, ed, keep = [], [], []
-    for s, d in edges:
-        si = lookup_src.get(s)
-        di = lookup_dst.get(d)
-        if si is not None and di is not None:
-            es.append(si)
-            ed.append(di)
-    es = np.asarray(es[:edge_cap], np.int32)
-    ed = np.asarray(ed[:edge_cap], np.int32)
+    edges = np.asarray(edges).reshape(-1, 2)
+    si = _last_slot(src, edges[:, 0])
+    di = _last_slot(dst, edges[:, 1])
+    keep = (si >= 0) & (di >= 0)
+    es = si[keep][:edge_cap].astype(np.int32)
+    ed = di[keep][:edge_cap].astype(np.int32)
     mask = np.zeros(edge_cap, bool)
     mask[:len(es)] = True
     return Block(
@@ -88,6 +98,16 @@ def _build_block(g: Graph, dst: np.ndarray, src_extra: np.ndarray,
         edge_dst=_pad_to(ed, edge_cap, 0),
         edge_mask=mask,
     )
+
+
+def _real_dst(dst: np.ndarray) -> np.ndarray:
+    """The real ids of a padded ``dst`` (-1 marks an empty slot)."""
+    real = dst[dst >= 0]
+    if len(np.unique(real)) != len(real):
+        # _build_block's slot lookup maps each id to ONE slot; duplicate
+        # dst ids would leave the other slots silently edge-less
+        raise ValueError("padded dst ids must be unique (dedup upstream)")
+    return real
 
 
 def sample_block_padded(g: Graph, gr: Graph, dst: np.ndarray, fanout: int,
@@ -108,17 +128,13 @@ def sample_block_padded(g: Graph, gr: Graph, dst: np.ndarray, fanout: int,
     ``picker(node, nbr)``, when given, replaces the per-node rng pick
     entirely (the delta-aware samplers memoize picks through it; any
     serving picker must stay a pure function of ``(node, nbr)`` to
-    preserve the determinism contract; :class:`NeighborSampler` draws from
-    its own seeded stream instead).
+    preserve the determinism contract).  :class:`NeighborSampler` does
+    not come here: it draws a whole layer at once from its own stream.
     """
     dst = np.asarray(dst, np.int64)
     dcap = len(dst)
+    _real_dst(dst)
     valid = dst >= 0
-    real = dst[valid]
-    if len(np.unique(real)) != len(real):
-        # _build_block's slot lookup maps each id to ONE slot; duplicate
-        # dst ids would leave the other slots silently edge-less
-        raise ValueError("padded dst ids must be unique (dedup upstream)")
     if expand is not None:
         valid = valid & expand
     edges, srcs = [], []
@@ -147,14 +163,33 @@ def sample_block_padded(g: Graph, gr: Graph, dst: np.ndarray, fanout: int,
 # neighbor sampling (GraphSAGE)
 # ===========================================================================
 
+def _floyd(rng: np.random.Generator, n: np.ndarray, k: int) -> np.ndarray:
+    """(len(n), k) positions, ascending: row i is a uniform k-subset of
+    ``range(n[i])`` (every ``n[i] >= k``), independent across rows.
+
+    Floyd's algorithm, one round per chosen position for all rows at once:
+    round r draws t uniform in [0, j] for j = n - k + r and keeps t, or j
+    where t was already chosen."""
+    out = np.empty((len(n), k), np.int64)
+    for r in range(k):
+        j = n - k + r
+        t = rng.integers(0, j + 1)
+        seen = (out[:, :r] == t[:, None]).any(axis=1)
+        out[:, r] = np.where(seen, j, t)
+    out.sort(axis=1)
+    return out
+
+
 class NeighborSampler:
     """Fixed-fanout neighbor sampling [GraphSAGE, Hamilton+ 2017].
 
     For each layer (outermost last) sample ``fanout`` in-neighbors per dst
     node without replacement (all of them if deg <= fanout).  Each layer
-    expands the previous layer's padded source ids with
-    :func:`sample_block_padded`, so a batch of ``B`` seeds always yields
-    blocks of ``B * prod(1 + f)`` source rows, whatever was sampled."""
+    expands the previous layer's padded source ids as
+    :func:`sample_block_padded` lays them out, so a batch of ``B`` seeds
+    always yields blocks of ``B * prod(1 + f)`` source rows, whatever was
+    sampled.  A layer is one draw over the reverse graph's CSR arrays for
+    all its destinations (:meth:`draw`), not a pick per node."""
 
     name = "neighbor"
 
@@ -164,20 +199,45 @@ class NeighborSampler:
         self.fanouts = list(fanouts)
         self.rng = np.random.default_rng(seed)
 
+    def draw(self, dst: np.ndarray, fanout: int) -> tuple:
+        """One layer's picks for the padded ``dst``: ``(edges, capped)``,
+        the (E, 2) [src, dst] edges in destination order, each
+        destination's neighbours in CSR order, and the number of
+        destinations with more than ``fanout`` in-neighbours, which got a
+        uniform ``fanout``-subset (:func:`_floyd`); the others get all."""
+        d = _real_dst(dst)
+        start = self.gr.row_ptr[d]
+        deg = self.gr.row_ptr[d + 1] - start
+        take = np.minimum(deg, fanout)
+        first = np.cumsum(take) - take
+        owner = np.repeat(np.arange(len(d)), take)
+        pos = np.arange(len(owner)) - first[owner]
+        capped = np.flatnonzero(deg > fanout)
+        if len(capped):
+            pos[first[capped][:, None] + np.arange(fanout)] = _floyd(
+                self.rng, deg[capped], fanout)
+        nbr = self.gr.col_idx[start[owner] + pos].astype(np.int64)
+        return np.stack([nbr, d[owner]], axis=1), len(capped)
+
     def sample(self, seeds: np.ndarray) -> MiniBatch:
-        """One mini-batch for ``seeds`` (span ``sampler.sample``)."""
-        with telemetry.span("sampler.sample"):
+        """One mini-batch for ``seeds`` (span ``sampler.sample``, with the
+        real ``edges`` sampled and the ``capped`` destinations drawn
+        without replacement, over all layers)."""
+        with telemetry.span("sampler.sample") as attrs:
             seeds = np.asarray(seeds, np.int64)
             blocks: List[Block] = []
             dst = seeds
+            n_edges = n_capped = 0
             for f in reversed(self.fanouts):
-                def pick(d, nbr, f=f):
-                    return nbr if len(nbr) <= f else self.rng.choice(
-                        nbr, f, replace=False)
-                blocks.append(sample_block_padded(self.g, self.gr, dst, f,
-                                                  None, picker=pick))
+                edges, capped = self.draw(dst, f)
+                n_edges += len(edges)
+                n_capped += capped
+                blocks.append(_build_block(self.g, dst, edges[:, 0], edges,
+                                           len(dst) * (1 + f), len(dst) * f))
                 dst = blocks[-1].src_nodes
             blocks.reverse()
+            if attrs is not None:
+                attrs.update(edges=n_edges, capped=n_capped)
             return MiniBatch(blocks, seeds, blocks[0].src_nodes)
 
 

@@ -4,8 +4,10 @@ GNN step).
 * a span records its thread's CPU seconds and, while a profiler trace is
   recorded, writes itself into that trace as ``repro.<name>`` with its
   attributes; with telemetry off and no trace it records nothing;
-* ``loader.get`` says whether a batch was waiting; ``store.fetch_masked``
-  counts slots, pad slots and bytes;
+* ``loader.get`` says whether a batch was waiting; ``sampler.sample``
+  counts the edges it sampled and the destinations whose in-degree
+  exceeded the fanout; ``store.fetch_masked`` counts slots, pad slots
+  and bytes;
 * the compiled mini-batch and full-graph steps name their aggregation,
   forward and backward, their dense work, normalisation, loss and
   optimizer in their HLO ``op_name`` metadata.
@@ -177,6 +179,24 @@ def test_loader_get_says_whether_a_batch_was_waiting(reg, ready):
         ["ready"] if ready else ["empty"])
     if not ready:
         assert gets[0]["dur"] >= 0.03
+
+
+def test_sampler_counts_edges_and_capped_destinations(reg):
+    from repro.core.sampling import NeighborSampler
+    from repro.graph import generators as G
+
+    g = G.sbm(200, 4, p_in=0.2, p_out=0.01, seed=1)
+    fanouts = [2, 3]
+    s = NeighborSampler(g, fanouts, seed=0)
+    mb = s.sample(np.arange(16))
+    (ev,) = [e for e in reg.tracer.events if e["name"] == "sampler.sample"]
+    in_deg = g.in_degree()
+    capped = sum(int(np.sum(in_deg[b.dst_nodes[b.dst_nodes >= 0]] > f))
+                 for b, f in zip(mb.blocks, fanouts))
+    assert capped > 0
+    assert ev["attrs"] == {
+        "edges": sum(int(b.edge_mask.sum()) for b in mb.blocks),
+        "capped": capped}
 
 
 def test_fetch_masked_counts_rows_pads_and_bytes(reg):
