@@ -151,21 +151,16 @@ def segment_max(msgs, seg_ids, num_segments):
                                indices_are_sorted=False)
 
 
-def segment_softmax(logits, seg_ids, num_segments, mask, *,
-                    use_kernel: bool = False):
-    """Per-destination softmax over incoming edges (GAT).
-
-    ``use_kernel`` reaches the denominator's :func:`segment_sum` too, so
-    a kernel-mode GAT runs every reduction through the Pallas path (the
-    max for numerical stability stays ``jax.ops.segment_max``).
-    """
-    neg = jnp.asarray(-1e30, logits.dtype)
-    logits = jnp.where(mask[:, None] if logits.ndim > 1 else mask,
-                       logits, neg)
+def segment_softmax(logits, seg_ids, num_segments, mask):
+    """Per-destination softmax over incoming edges (GAT), per column of
+    ``logits`` ((E,) or (E, heads)); masked edges get weight 0.  Plain
+    ``jax.ops``: the kernel paths compute theirs inside
+    :func:`repro.kernels.ops.gat_attention`."""
+    m = mask[:, None] if logits.ndim > 1 else mask
+    logits = jnp.where(m, logits, jnp.asarray(-1e30, logits.dtype))
     mx = segment_max(logits, seg_ids, num_segments)
-    ex = jnp.exp(logits - mx[seg_ids])
-    ex = ex * (mask[:, None] if logits.ndim > 1 else mask)
-    den = segment_sum(ex, seg_ids, num_segments, use_kernel=use_kernel)
+    ex = jnp.exp(logits - mx[seg_ids]) * m
+    den = jax.ops.segment_sum(ex, seg_ids, num_segments)
     return ex / (den[seg_ids] + 1e-9)
 
 

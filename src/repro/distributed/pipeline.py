@@ -137,7 +137,9 @@ def make_distributed_minibatch_step(cfg: GNNConfig, optimizer, n_dev: int,
 
     train_step(params, opt_state, arrays) -> (params, opt_state, loss)
     with ``arrays`` from :func:`collate`; params/opt_state replicated,
-    gradients psum'd over ``"g"`` (decentralized all-reduce).
+    gradients psum'd over ``"g"`` (decentralized all-reduce, scope
+    ``dist.grad_psum``).  The step is jitted as a whole, so
+    ``train_step.lower(...)`` gives its compiled program.
 
     ``cfg.use_kernel=True`` runs every block layer's aggregation through
     the differentiable Pallas kernels (``forward_blocks`` forwards the
@@ -179,8 +181,10 @@ def make_distributed_minibatch_step(cfg: GNNConfig, optimizer, n_dev: int,
             return total / cnt           # this device's share of the mean
 
         local_loss, grads = jax.value_and_grad(loss_fn)(params)
-        loss = jax.lax.psum(local_loss, AXIS)
-        grads = jax.tree.map(lambda a: jax.lax.psum(a, AXIS), grads)
+        # the loss's psum beside the gradients': XLA may combine them
+        with jax.named_scope("dist.grad_psum"):
+            loss = jax.lax.psum(local_loss, AXIS)
+            grads = jax.tree.map(lambda a: jax.lax.psum(a, AXIS), grads)
         params, opt_state = optimizer.apply(params, grads, opt_state)
         return params, opt_state, loss
 
@@ -190,11 +194,11 @@ def make_distributed_minibatch_step(cfg: GNNConfig, optimizer, n_dev: int,
         in_specs=(rep, rep, shard, shard, shard, shard, shard, shard,
                   shard),
         out_specs=(rep, rep, rep), check_vma=False)
-    jitted = jax.jit(smapped)
 
-    def train_step(params, opt_state, arrays: dict):
-        return jitted(params, opt_state, arrays["es"], arrays["ed"],
-                      arrays["em"], arrays["sdeg"], arrays["x"],
-                      arrays["y"], arrays["w"])
+    @jax.jit
+    def partition_step(params, opt_state, arrays: dict):
+        return smapped(params, opt_state, arrays["es"], arrays["ed"],
+                       arrays["em"], arrays["sdeg"], arrays["x"],
+                       arrays["y"], arrays["w"])
 
-    return mesh, train_step
+    return mesh, partition_step

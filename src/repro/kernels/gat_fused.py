@@ -52,14 +52,20 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.segment_sum import (DEFAULT_BE, DEFAULT_BN, SUBLANE,
-                                       VMEM_BUDGET, _assert_vmem, _dot,
-                                       _edge_column, _edge_dot, _fused_impl,
-                                       _pad_edges, _pick_bf,
+from repro.kernels.segment_sum import (DEFAULT_BE, DEFAULT_BN, LANE,
+                                       SUBLANE, VMEM_BUDGET, _assert_vmem,
+                                       _dot, _edge_column, _edge_dot,
+                                       _fused_impl, _pad_edges, _pick_bf,
                                        fused_vmem_floats, hbm_bytes_jax_ops)
 
 NEG_INF = -1e30
 LEAKY_SLOPE = 0.2
+
+
+def _lanes(n: int) -> int:
+    """``n`` rounded up to whole 128-lane rows, as VMEM holds a block's
+    last dimension."""
+    return -(-n // LANE) * LANE
 
 
 def _pad8(n: int) -> int:
@@ -180,16 +186,18 @@ def _gat_impl(hs, es, ed, edge_src, edge_dst, maskf, num_dst, heads, be,
 
 def _reference_alphas(es, ed, edge_src, edge_dst, maskf, num_dst):
     """(E, heads) attention weights of the multi-pass reference (XLA ops;
-    the flash-style backward recomputes these instead of saving them)."""
-    pre = (jnp.take(es, edge_src, axis=0)
-           + jnp.take(ed, edge_dst, axis=0))               # (E, H)
-    z = jnp.where(pre >= 0, pre, LEAKY_SLOPE * pre)
-    zm = jnp.where(maskf[:, None] > 0, z, NEG_INF)
-    mx = jax.ops.segment_max(zm, edge_dst, num_dst)
-    mx = jnp.where(jnp.isfinite(mx), mx, 0.0)              # empty segments
-    ex = jnp.exp(zm - mx[edge_dst]) * maskf[:, None]
-    den = jax.ops.segment_sum(ex, edge_dst, num_dst)
-    return ex / (den[edge_dst] + 1e-9), pre
+    the flash-style backward recomputes these instead of saving them;
+    scope ``edge_softmax``)."""
+    with jax.named_scope("edge_softmax"):
+        pre = (jnp.take(es, edge_src, axis=0)
+               + jnp.take(ed, edge_dst, axis=0))           # (E, H)
+        z = jnp.where(pre >= 0, pre, LEAKY_SLOPE * pre)
+        zm = jnp.where(maskf[:, None] > 0, z, NEG_INF)
+        mx = jax.ops.segment_max(zm, edge_dst, num_dst)
+        mx = jnp.where(jnp.isfinite(mx), mx, 0.0)          # empty segments
+        ex = jnp.exp(zm - mx[edge_dst]) * maskf[:, None]
+        den = jax.ops.segment_sum(ex, edge_dst, num_dst)
+        return ex / (den[edge_dst] + 1e-9), pre
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
@@ -275,13 +283,16 @@ def gat_fused_vmem_floats(num_src: int, num_dst: int, heads: int, hd: int,
     hdp = _pick_bf(hd)
     hp = _pad8(heads)
     sp = _pad8(num_src)
-    fwd = (sp * heads * hdp + sp * hp          # hs + es slabs resident
-           + bn * hp                           # ed tile
-           + be * sp + be * bn                 # both one-hots
-           + 3 * be * hp                       # es_e/ed_e/logits
-           + be * hdp + bn * hdp + be * bn     # msgs/contrib/cond
-           + bn * (2 * hp + 2 * heads * hdp)   # m/l/acc/out
-           + 3 * be)                           # ids + mask
+    # VMEM lays every block out in whole 128-lane rows: an (Sp, 8) logit
+    # slab takes as much room as an (Sp, 128) one
+    hw, hl = _lanes(heads * hdp), _lanes(hp)
+    fwd = (sp * hw + sp * hl                   # hs + es slabs resident
+           + bn * hl                           # ed tile
+           + be * _lanes(sp) + be * _lanes(bn)  # both one-hots
+           + 3 * be * hl                       # es_e/ed_e/logits
+           + be * _lanes(hdp) + bn * _lanes(hdp) + be * _lanes(bn)
+           + bn * (2 * hl + 2 * hw)            # m/l/acc/out
+           + 3 * be * LANE)                    # ids + mask columns
     bwd = fused_vmem_floats(max(num_src, num_dst),
                             max(num_src, num_dst), hd, be=be, bn=bn)
     return max(fwd, bwd)

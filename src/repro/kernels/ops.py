@@ -165,23 +165,23 @@ def _gat_fused_jit(hs, es, ed, edge_src, edge_dst, mask, num_dst: int,
 def _gat_multipass_jit(hs, es, ed, edge_src, edge_dst, mask,
                        num_dst: int, heads: int, interpret: bool):
     """The multi-pass kernel path the fused kernel replaces: logits and
-    alphas materialize as (E, heads) tensors; the segment reductions run
-    through the blocked Pallas kernels (mirrors
-    ``abstraction.segment_softmax`` + ``segment_sum`` in kernel mode)."""
+    alphas materialize as (E, heads) tensors (scope ``edge_softmax``); the
+    segment sums run through the blocked Pallas kernels."""
     E = edge_src.shape[0]
     hd = hs.shape[1] // heads
     maskf = mask.astype(jnp.float32)
-    pre = (jnp.take(es, edge_src, axis=0)
-           + jnp.take(ed, edge_dst, axis=0))
-    logits = jax.nn.leaky_relu(pre, 0.2)
-    neg = jnp.asarray(-1e30, logits.dtype)
-    logits = jnp.where(maskf[:, None] > 0, logits, neg)
-    mx = jax.ops.segment_max(logits, edge_dst, num_dst,
-                             indices_are_sorted=False)
-    ex = jnp.exp(logits - mx[edge_dst]) * maskf[:, None]
-    den = _ss.segment_sum_pallas(ex, edge_dst, num_dst,
-                                 interpret=interpret)
-    alpha = ex / (den[edge_dst] + 1e-9)
+    with jax.named_scope("edge_softmax"):
+        pre = (jnp.take(es, edge_src, axis=0)
+               + jnp.take(ed, edge_dst, axis=0))
+        logits = jax.nn.leaky_relu(pre, 0.2)
+        neg = jnp.asarray(-1e30, logits.dtype)
+        logits = jnp.where(maskf[:, None] > 0, logits, neg)
+        mx = jax.ops.segment_max(logits, edge_dst, num_dst,
+                                 indices_are_sorted=False)
+        ex = jnp.exp(logits - mx[edge_dst]) * maskf[:, None]
+        den = _ss.segment_sum_pallas(ex, edge_dst, num_dst,
+                                     interpret=interpret)
+        alpha = ex / (den[edge_dst] + 1e-9)
     msgs = (jnp.take(hs.reshape(-1, heads, hd), edge_src, axis=0)
             * alpha[..., None])
     return _ss.segment_sum_pallas(msgs.reshape(E, heads * hd), edge_dst,

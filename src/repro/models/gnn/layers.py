@@ -15,7 +15,7 @@ import numpy as np
 
 from repro.core.abstraction import (DeviceGraph, MessagePassing,
                                     gather_scale_segment_sum,
-                                    segment_softmax, segment_sum)
+                                    segment_softmax)
 from repro.core.comm import QuantizedRows
 
 
@@ -100,18 +100,45 @@ class SAGELayer(MessagePassing):
 
 
 class GATLayer(MessagePassing):
-    """Single-projection multi-head GAT with per-destination softmax."""
+    """GAT [Velickovic+ 2018] as PyG's ``GATConv`` builds it, with the skip
+    path of PyG's ogbn-products example (``examples/ogbn_products_gat.py``)::
 
-    def __init__(self, heads: int = 4):
-        self.heads = heads
+        Wh      = x_src @ W                   one projection, heads x C wide
+        z[e, k] = leaky_relu(a_src[k] . Wh[src e, k] + a_dst[k] . Wh[dst e, k], 0.2)
+        alpha   = softmax of z over each destination's in-edges, per head k
+        conv[d] = concat_k or mean_k (sum_e alpha[e, k] Wh[src e, k]) + b
+        h'[d]   = conv[d] + x_dst[d] @ W_skip + b_skip
+
+    Each destination attends to itself too: the layer drops the graph's
+    own self-loops and adds one per destination (PyG's
+    ``remove_self_loops`` then ``add_self_loops``), with source row ``i``
+    as destination ``i``, since a block's destinations are the prefix of
+    its sources.  Hidden layers concatenate the heads; the last layer
+    averages them (``concat=False``), which the layer reads from the width
+    of its bias.  Attention vectors are ``(1, heads, C)``, PyG's layout.
+
+    Scopes: ``gnn.dense`` (the projection, the attention halves, the
+    bias), ``gnn.aggregate`` with the implementation under it and, where
+    the softmax runs as operations of its own, ``edge_softmax`` under
+    that; ``gnn.skip``."""
 
     @staticmethod
-    def init(key, din, dout, heads: int = 4):
-        hd = dout // heads
-        k1, k2, k3 = jax.random.split(key, 3)
-        return {"w": _dense(k1, din, dout),
-                "a_src": jax.random.normal(k2, (heads, hd), jnp.float32) * 0.1,
-                "a_dst": jax.random.normal(k3, (heads, hd), jnp.float32) * 0.1}
+    def init(key, din, dout, heads: int = 4, concat: bool = True):
+        """``dout`` is the layer's output width: ``heads * C`` where the
+        heads are concatenated, ``C`` where they are averaged."""
+        if concat and dout % heads:
+            raise ValueError(f"a concatenating GAT layer of width {dout} "
+                             f"cannot split over {heads} heads")
+        c = dout // heads if concat else dout
+        k1, k2, k3, k4 = jax.random.split(key, 4)
+        return {"w": _dense(k1, din, heads * c),
+                "a_src": jax.random.normal(k2, (1, heads, c), jnp.float32)
+                / np.sqrt(c),
+                "a_dst": jax.random.normal(k3, (1, heads, c), jnp.float32)
+                / np.sqrt(c),
+                "b": jnp.zeros((dout,), jnp.float32),
+                "w_skip": _dense(k4, din, dout),
+                "b_skip": jnp.zeros((dout,), jnp.float32)}
 
     def __call__(self, p, g: DeviceGraph, x_src, x_dst=None, *,
                  use_kernel=False):
@@ -119,31 +146,46 @@ class GATLayer(MessagePassing):
             # attention projects before aggregating, so the int8-in
             # kernel path does not apply — decode up front
             x_src = jnp.asarray(x_src.dequantize())
-        if x_dst is None:
+        prefix = x_dst is None
+        if prefix:
             x_dst = x_src[:g.num_dst]
-        heads, hd = p["a_src"].shape
+        _, heads, c = p["a_src"].shape
         with jax.named_scope("gnn.dense"):
-            hs = (x_src @ p["w"]).reshape(-1, heads, hd)
-            hdst = (x_dst @ p["w"]).reshape(-1, heads, hd)
-            es = jnp.einsum("nhd,hd->nh", hs, p["a_src"])
-            ed = jnp.einsum("nhd,hd->nh", hdst, p["a_dst"])
+            hs = x_src @ p["w"]                              # (S, H*C)
+            # the destinations' rows are the sources' first rows
+            hd = hs[:g.num_dst] if prefix else x_dst @ p["w"]
+            es = jnp.sum(hs.reshape(-1, heads, c) * p["a_src"], axis=-1)
+            ed = jnp.sum(hd.reshape(-1, heads, c) * p["a_dst"], axis=-1)
         with jax.named_scope("gnn.aggregate"):
+            loops = jnp.arange(g.num_dst, dtype=g.edge_src.dtype)
+            src = jnp.concatenate([g.edge_src, loops])
+            dst = jnp.concatenate([g.edge_dst, loops])
+            mask = jnp.concatenate([g.edge_mask & (g.edge_src != g.edge_dst),
+                                    jnp.ones((g.num_dst,), bool)])
             if use_kernel:
-                # one-pass fused online-softmax kernel: edge logits and
-                # alphas never reach HBM (falls back to the multi-pass
-                # kernel path when the VMEM capacity predicate says no)
+                # one-pass fused online-softmax kernel where its slabs fit
+                # VMEM, else the multi-pass kernel path
                 from repro.kernels import ops as kops
-                return kops.gat_attention(
-                    hs.reshape(-1, heads * hd), es, ed, g.edge_src,
-                    g.edge_dst, g.edge_mask, g.num_dst, heads=heads)
-            logits = jax.nn.leaky_relu(
-                jnp.take(es, g.edge_src, axis=0)
-                + jnp.take(ed, g.edge_dst, axis=0), 0.2)    # (E, heads)
-            alpha = segment_softmax(logits, g.edge_dst, g.num_dst,
-                                    g.edge_mask, use_kernel=use_kernel)
-            msgs = jnp.take(hs, g.edge_src, axis=0) * alpha[..., None]
-            return segment_sum(msgs.reshape(-1, heads * hd), g.edge_dst,
-                               g.num_dst, use_kernel=use_kernel)
+                out = kops.gat_attention(hs, es, ed, src, dst, mask,
+                                         g.num_dst, heads=heads)
+            else:
+                with jax.named_scope("jax_ops"):
+                    with jax.named_scope("edge_softmax"):
+                        logits = jax.nn.leaky_relu(
+                            jnp.take(es, src, axis=0)
+                            + jnp.take(ed, dst, axis=0), 0.2)   # (E, H)
+                        alpha = segment_softmax(logits, dst, g.num_dst,
+                                                mask)
+                    msgs = (jnp.take(hs.reshape(-1, heads, c), src, axis=0)
+                            * alpha[..., None])
+                    out = jax.ops.segment_sum(msgs.reshape(-1, heads * c),
+                                              dst, g.num_dst)
+        with jax.named_scope("gnn.dense"):
+            if p["b"].shape[0] != heads * c:                 # head mean
+                out = out.reshape(-1, heads, c).mean(axis=1)
+            out = out + p["b"]
+        with jax.named_scope("gnn.skip"):
+            return out + x_dst @ p["w_skip"] + p["b_skip"]
 
 
 class GINLayer(MessagePassing):

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.abstraction import DeviceGraph, gather_scale_segment_sum
-from repro.models.gnn.layers import LAYER_TYPES, GATLayer
+from repro.models.gnn.layers import LAYER_TYPES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -21,7 +21,7 @@ class GNNConfig:
     hidden: int = 128
     num_classes: int = 8
     num_layers: int = 2
-    gat_heads: int = 4
+    gat_heads: int = 4                # GAT: hidden = gat_heads * head width
     appnp_k: int = 4                  # APPNP propagation hops
     appnp_alpha: float = 0.1
     use_kernel: bool = False          # Pallas segment-sum for aggregation
@@ -43,17 +43,23 @@ def init_gnn(cfg: GNNConfig, key) -> List[dict]:
     for i in range(cfg.num_layers):
         k = jax.random.fold_in(key, i)
         if cfg.arch == "gat":
+            # hidden layers concatenate their heads, the last averages them
             params.append(layer_cls.init(k, dims[i], dims[i + 1],
-                                         heads=cfg.gat_heads))
+                                         heads=cfg.gat_heads,
+                                         concat=i + 1 < cfg.num_layers))
         else:
             params.append(layer_cls.init(k, dims[i], dims[i + 1]))
     return params
 
 
 def _make_layer(cfg: GNNConfig):
-    if cfg.arch == "gat":
-        return GATLayer(cfg.gat_heads)
     return LAYER_TYPES[cfg.arch]()
+
+
+def _activation(cfg: GNNConfig):
+    """The nonlinearity between layers: ELU for GAT, as published; ReLU
+    for the rest."""
+    return jax.nn.elu if cfg.arch == "gat" else jax.nn.relu
 
 
 def forward_full(cfg: GNNConfig, params, g: DeviceGraph, x) -> jax.Array:
@@ -67,13 +73,13 @@ def forward_full(cfg: GNNConfig, params, g: DeviceGraph, x) -> jax.Array:
         for _ in range(cfg.appnp_k):
             h = layer.propagate(g, h, h0, use_kernel=cfg.use_kernel)
         return h
-    layer = _make_layer(cfg)
+    layer, act = _make_layer(cfg), _activation(cfg)
     h = x
     for i, p in enumerate(params):
         h = layer(p, g, h, use_kernel=cfg.use_kernel)
         if i + 1 < len(params):
             with jax.named_scope("gnn.dense"):
-                h = jax.nn.relu(h)
+                h = act(h)
     return h
 
 
@@ -81,13 +87,13 @@ def forward_blocks(cfg: GNNConfig, params, blocks: Sequence[DeviceGraph],
                    x_input) -> jax.Array:
     """Mini-batch forward over sampled bipartite blocks (DistDGL style).
     ``x_input``: features of blocks[0].src_nodes."""
-    layer = _make_layer(cfg)
+    layer, act = _make_layer(cfg), _activation(cfg)
     h = x_input
     for i, (p, g) in enumerate(zip(params, blocks)):
         h = layer(p, g, h, use_kernel=cfg.use_kernel)
         if i + 1 < len(params):
             with jax.named_scope("gnn.dense"):
-                h = jax.nn.relu(h)
+                h = act(h)
     return h
 
 
@@ -211,11 +217,11 @@ def forward_blocks_cached(cfg: GNNConfig, params,
     ``h_fresh`` is the pre-splice hidden state — the rows to write back for
     cache misses.  Shapes are static per (bucket, fanouts), so each bucket
     compiles once."""
-    layer = _make_layer(cfg)
+    layer, act = _make_layer(cfg), _activation(cfg)
     h = x_input
     for i in range(len(params) - 1):
         h = layer(params[i], inner_blocks[i], h, use_kernel=cfg.use_kernel)
-        h = jax.nn.relu(h)
+        h = act(h)
     h_fresh = h
     h = jnp.where(fresh_mask[:, None], cached_h, h_fresh)
     logits = layer(params[-1], outer_block, h, use_kernel=cfg.use_kernel)

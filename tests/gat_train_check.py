@@ -4,10 +4,11 @@ set before JAX imports.
 
 argv: n_dev
 
-Trains 10 full-graph GAT steps with ``use_kernel=True`` (the fused
-online-softmax Pallas kernel, interpret mode on CPU) and with the XLA
-reference path from the same init, then demands every parameter agree to
-<= 1e-5 — ``jax.grad`` through the composed custom VJP (alpha recompute
+Trains 10 full-graph steps of the published GAT layer (self-loops,
+concatenated hidden heads, head-mean output, bias, skip path, ELU) with
+``use_kernel=True`` (the fused online-softmax Pallas kernel, interpret
+mode on CPU) and with the XLA path from the same init, then demands every
+parameter agree to <= 1e-5 — ``jax.grad`` through the composed custom VJP (alpha recompute
 + swapped fused kernels + closed-form softmax backward) matches XLA
 autodiff step for step.
 

@@ -31,7 +31,7 @@ def test_forward_shapes(sbm_graph, arch):
     assert not np.any(np.isnan(np.asarray(logits)))
 
 
-@pytest.mark.parametrize("arch", ["gcn", "sage", "gin"])
+@pytest.mark.parametrize("arch", ["gcn", "sage", "gin", "gat"])
 def test_kernel_path_matches_reference(sbm_graph, arch):
     cfg_ref = GNNConfig(arch=arch, feat_dim=16, hidden=32, num_classes=4)
     cfg_k = GNNConfig(arch=arch, feat_dim=16, hidden=32, num_classes=4,

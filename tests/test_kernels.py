@@ -179,9 +179,10 @@ def test_fused_no_edges():
 # ---------------------------------------------------------------------------
 
 def _gat_ref(hs, es, ed, src, dst, maskf, N, heads):
-    """Multi-pass XLA reference: the exact math GATLayer's non-kernel
-    path runs (leaky-relu logits, per-destination softmax with the
-    same 1e-9 denominator, weighted segment sum)."""
+    """Multi-pass XLA reference: the attention GATLayer's ``jax_ops``
+    path runs over its edges (leaky-relu logits, per-destination softmax
+    with the same 1e-9 denominator, weighted segment sum, heads
+    concatenated; the layer averages them in its last layer)."""
     hd = hs.shape[1] // heads
     logits = jax.nn.leaky_relu(
         jnp.take(es, src, axis=0) + jnp.take(ed, dst, axis=0), 0.2)
@@ -196,15 +197,23 @@ def _gat_ref(hs, es, ed, src, dst, maskf, N, heads):
     return jax.ops.segment_sum(msgs.reshape(-1, heads * hd), dst, N)
 
 
-def _gat_case(S, E, N, heads, hd, seed=0, mask_frac=0.0):
+def _gat_case(S, E, N, heads, hd, seed=0, mask_frac=0.0, loops=False):
+    """Random attention inputs; with ``loops``, the edge set GATLayer
+    hands the kernel: ``E`` sampled edges and then one self-loop per
+    destination (source row ``i`` is destination ``i``)."""
     rng = np.random.default_rng(seed)
     hs = jnp.asarray(rng.normal(size=(S, heads * hd)), jnp.float32)
     es = jnp.asarray(rng.normal(size=(S, heads)), jnp.float32) * 0.3
     ed = jnp.asarray(rng.normal(size=(N, heads)), jnp.float32) * 0.3
-    src = jnp.asarray(rng.integers(0, S, E), jnp.int32)
-    dst = jnp.asarray(rng.integers(0, N, E), jnp.int32)
-    mask = jnp.asarray(rng.random(E) >= mask_frac)
-    return hs, es, ed, src, dst, mask
+    src = rng.integers(0, S, E)
+    dst = rng.integers(0, N, E)
+    mask = rng.random(E) >= mask_frac
+    if loops:
+        src = np.concatenate([src, np.arange(N)])
+        dst = np.concatenate([dst, np.arange(N)])
+        mask = np.concatenate([mask, np.ones(N, bool)])
+    return (hs, es, ed, jnp.asarray(src, jnp.int32),
+            jnp.asarray(dst, jnp.int32), jnp.asarray(mask))
 
 
 @pytest.mark.parametrize("S,E,N,heads,hd", [
@@ -213,7 +222,7 @@ def _gat_case(S, E, N, heads, hd, seed=0, mask_frac=0.0):
 ])
 def test_gat_fused_forward_matches_reference(S, E, N, heads, hd):
     hs, es, ed, src, dst, mask = _gat_case(S, E, N, heads, hd,
-                                           mask_frac=0.2)
+                                           mask_frac=0.2, loops=True)
     got = gat_fused_attention_pallas(hs, es, ed, src, dst, mask, N,
                                      heads=heads)
     want = _gat_ref(hs, es, ed, src, dst, mask.astype(jnp.float32), N,
@@ -229,7 +238,7 @@ def test_gat_fused_grads_match_reference(S, E, N, heads, hd):
     kernels + closed-form softmax backward) matches XLA autodiff through
     the multi-pass expression on every differentiable input."""
     hs, es, ed, src, dst, mask = _gat_case(S, E, N, heads, hd, seed=1,
-                                           mask_frac=0.2)
+                                           mask_frac=0.2, loops=True)
     maskf = mask.astype(jnp.float32)
     w = jnp.asarray(np.random.default_rng(9).normal(
         size=(N, heads * hd)), jnp.float32)

@@ -340,3 +340,24 @@ def _uniform(g):
 def test_neighbor_sampler_layer_draw(case):
     {"exact_fanout": _exact_fanout, "same_seed": _same_seed,
      "threads": _threads, "uniform": _uniform}[case](_ladder_graph())
+
+
+@pytest.mark.parametrize("sampler", ["neighbor", "distributed"])
+def test_destinations_are_the_first_sources(graph, sampler):
+    """What GAT's self-loops and skip path rely on: in every block, source
+    slot ``i`` holds destination ``i`` for each of the ``n_dst`` slots,
+    padded slots included, so ``x_src[:n_dst]`` are the destinations."""
+    seeds = np.random.default_rng(4).choice(graph.num_nodes, 24,
+                                            replace=False)
+    if sampler == "neighbor":
+        blocks = S.NeighborSampler(graph, [3, 2, 4], seed=1).sample(
+            seeds).blocks
+    else:
+        from repro.distributed import DistributedMinibatchSampler
+        ds = DistributedMinibatchSampler(graph, 3, [3, 2, 4], 16,
+                                         cache_capacity=30)
+        owned = ds.layout.owned[1]
+        blocks = ds.sample_partition(1, owned[:11]).blocks   # 5 pad seeds
+    assert any((b.dst_nodes < 0).any() for b in blocks)
+    for b in blocks:
+        np.testing.assert_array_equal(b.src_nodes[:b.num_dst], b.dst_nodes)
