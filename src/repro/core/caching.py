@@ -31,6 +31,8 @@ exactly the same thing on both paths.
 """
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Iterable, Optional, Union
 
 import numpy as np
@@ -46,6 +48,37 @@ from repro.graph.structure import Graph
 # sentinel version for "never written"; large-negative (not int64 min) so
 # computing ``clock - NEVER`` cannot overflow int64
 NEVER = -(2 ** 62)
+
+# FeatureStore.fetch_masked builds a matrix of at least _POOL_MIN_BYTES on
+# _POOL, in contiguous parts of about _PART_BYTES each, so the gather and
+# the first touch of the fresh pages spread over cores (np.take releases
+# the GIL); a smaller matrix (serving batches, test graphs) is built by
+# the same pass on the calling thread.  Pool threads never submit, so
+# concurrent callers (the partition stores' loader workers) share it.
+_POOL_MIN_BYTES = 16 << 20
+_PART_BYTES = 8 << 20
+_POOL = ThreadPoolExecutor(min(8, len(os.sched_getaffinity(0))),
+                           thread_name_prefix="fetch_masked")
+
+
+def _gather_rows(out: np.ndarray, features: np.ndarray, safe: np.ndarray,
+                 needed: np.ndarray, a: int, b: int) -> None:
+    """Write slots ``[a, b)`` of ``out`` once, run by run of ``needed``:
+    ``features[safe]`` where it holds, exact ``+0.0`` rows elsewhere.
+    The samplers lay a batch out in a few runs (real ids, then pads, per
+    layer).  ``safe`` holds valid row ids, so ``mode="clip"`` never
+    clips; it lets ``np.take`` write into ``out`` without a buffer."""
+    if a == b:
+        return
+    need = needed[a:b]
+    cuts = np.flatnonzero(need[1:] != need[:-1]) + (a + 1)
+    fill = bool(need[0])
+    for s, e in zip([a, *cuts.tolist()], [*cuts.tolist(), b]):
+        if fill:
+            np.take(features, safe[s:e], axis=0, out=out[s:e], mode="clip")
+        else:
+            out[s:e] = 0
+        fill = not fill
 
 
 class VersionClock:
@@ -213,9 +246,16 @@ class FeatureStore:
         and a call whose mask selects no rows (or only local/cache hits)
         issues no remote request — it adds 0 bytes, not a header.
 
+        The result is a fresh C-contiguous matrix, each slot written
+        once (:func:`_gather_rows`; ``self.cached[safe]`` has already
+        rejected an id out of range).  Under the identity codec a
+        float32 miss row crosses the wire unchanged, so it is only
+        accounted, not sent back.
+
         Span ``store.fetch_masked``: ``rows`` (slots), ``pad_rows``
-        (slots returned as zero rows) and ``bytes`` (of the returned
-        array)."""
+        (slots returned as zero rows), ``bytes`` (of the returned
+        array) and ``parts`` (parts gathered on the pool, 0 when the
+        calling thread built the matrix)."""
         with telemetry.span("store.fetch_masked") as attrs:
             ids = np.asarray(ids)
             needed = np.asarray(needed, bool) & (ids >= 0)
@@ -228,20 +268,35 @@ class FeatureStore:
             miss_rows = int(miss.sum())
             self.misses += miss_rows
             self._m_misses.inc(miss_rows)
+            parts = 0
             if self.g.features is None:
                 if miss_rows:
                     self.transport.account_opaque(miss_rows, 4)
                 out = safe
             else:
-                out = np.zeros((len(ids), self.g.features.shape[1]),
-                               self.g.features.dtype)
-                out[needed] = self.g.features[safe[needed]]
+                feats = self.g.features
+                n = len(ids)
+                out = np.empty((n, feats.shape[1]), feats.dtype)
+                if out.nbytes < _POOL_MIN_BYTES:
+                    _gather_rows(out, feats, safe, needed, 0, n)
+                else:
+                    step = max(1, _PART_BYTES // out[:1].nbytes)
+                    starts = range(0, n, step)
+                    parts = len(starts)
+                    list(_POOL.map(
+                        lambda a: _gather_rows(out, feats, safe, needed, a,
+                                               min(a + step, n)),
+                        starts))
                 if miss_rows:
-                    out[miss] = self._pull_remote(out[miss], safe[miss])
+                    if self.codec.identity and out.dtype == np.float32:
+                        self.transport.account_opaque(miss_rows,
+                                                      self.bytes_per_row)
+                    else:
+                        out[miss] = self._pull_remote(out[miss], safe[miss])
             if attrs is not None:
                 attrs.update(rows=len(ids),
                              pad_rows=len(ids) - int(needed.sum()),
-                             bytes=out.nbytes)
+                             bytes=out.nbytes, parts=parts)
         return out
 
     def fetch_masked_wire(self, ids: np.ndarray,

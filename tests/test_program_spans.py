@@ -6,8 +6,8 @@ GNN step).
   attributes; with telemetry off and no trace it records nothing;
 * ``loader.get`` says whether a batch was waiting; ``sampler.sample``
   counts the edges it sampled and the destinations whose in-degree
-  exceeded the fanout; ``store.fetch_masked`` counts slots, pad slots
-  and bytes;
+  exceeded the fanout; ``store.fetch_masked`` counts slots, pad slots,
+  bytes and the parts gathered on the pool;
 * the compiled mini-batch and full-graph steps name their aggregation,
   forward and backward, their dense work, normalisation, loss and
   optimizer in their HLO ``op_name`` metadata.
@@ -210,7 +210,8 @@ def test_fetch_masked_counts_rows_pads_and_bytes(reg):
     needed[3] = False                       # a real id whose row is not needed
     out = store.fetch_masked(ids, needed)
     (ev,) = [e for e in reg.tracer.events if e["name"] == "store.fetch_masked"]
-    assert ev["attrs"] == {"rows": 8, "pad_rows": 4, "bytes": out.nbytes}
+    assert ev["attrs"] == {"rows": 8, "pad_rows": 4, "bytes": out.nbytes,
+                           "parts": 0}
     assert out.nbytes == 8 * 8 * 4
     assert np.array_equal(out[~needed], np.zeros((4, 8), np.float32))
 
