@@ -9,8 +9,7 @@ A second, artifact-free section maps the locality-reordering policies
 (survey §3.2.4) onto the blocked kernels' tile geometry: per graph x
 policy it emits the VMEM-residency / tile-density metrics
 (``repro.kernels.segment_sum.edge_tile_density``) next to the static
-locality numbers — the roofline-side explanation for the wall-clock
-``reorder_speedup`` measured in bench_kernels.
+locality numbers.
 """
 import json
 import os

@@ -10,7 +10,6 @@ Prints ``name,us_per_call,derived`` CSV.  Module map:
   bench_scheduling    — Table 8  (§3.2.8)
   bench_datasets      — Table 9  (§3.2.10)
   bench_performance   — §3.2.12 system-lineage comparison
-  bench_kernels       — Pallas kernels vs oracles
   bench_roofline      — deliverable (g): roofline terms from the dry-run
   bench_serving       — online inference: cache hierarchy vs no-cache
   bench_async         — §3.2.7 staleness-bounded async full-graph training
@@ -23,9 +22,9 @@ import traceback
 
 from benchmarks import (bench_abstraction, bench_async, bench_caching,
                         bench_datasets, bench_distributed, bench_dynamic,
-                        bench_kernels, bench_partitioning,
-                        bench_performance, bench_roofline, bench_sampling,
-                        bench_scheduling, bench_serving)
+                        bench_partitioning, bench_performance,
+                        bench_roofline, bench_sampling, bench_scheduling,
+                        bench_serving)
 
 MODULES = [
     ("partitioning", bench_partitioning),
@@ -35,7 +34,6 @@ MODULES = [
     ("scheduling", bench_scheduling),
     ("datasets", bench_datasets),
     ("performance", bench_performance),
-    ("kernels", bench_kernels),
     ("distributed", bench_distributed),
     ("roofline", bench_roofline),
     ("serving", bench_serving),

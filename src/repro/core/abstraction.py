@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import telemetry
-from repro.core.comm import QuantizedRows
 from repro.core.sampling import Block
 from repro.graph.structure import Graph
 
@@ -116,17 +115,6 @@ def gather_scale_segment_sum(h, edge_src, edge_dst, coef, num_dst, *,
     the kernel wrapper's own).
     """
     with jax.named_scope("gnn.aggregate"):
-        if isinstance(h, QuantizedRows):
-            # int8-in path: wire-format rows aggregate without a decode
-            # round-trip on the kernel path; the reference path decodes
-            # first (same math the kernel performs per source slab)
-            if use_kernel:
-                from repro.kernels import ops as kops
-                return kops.gather_scale_segment_sum_q(
-                    jnp.asarray(h.q), jnp.asarray(h.mn),
-                    jnp.asarray(h.scale), edge_src, edge_dst, coef,
-                    num_dst)
-            h = jnp.asarray(h.dequantize())
         if use_kernel:
             from repro.kernels import ops as kops
             return kops.gather_scale_segment_sum(h, edge_src, edge_dst,
@@ -216,11 +204,6 @@ class MessagePassing:
 
     def __call__(self, p, g: DeviceGraph, x_src, x_dst=None, *,
                  use_kernel=False):
-        if isinstance(x_src, QuantizedRows):
-            # generic layers scatter fp32 rows onto edges; only layers
-            # that aggregate before projecting (SAGE) consume the wire
-            # format directly
-            x_src = jnp.asarray(x_src.dequantize())
         if x_dst is None:
             x_dst = x_src[:g.num_dst]
         return saga_layer(

@@ -41,8 +41,7 @@ import numpy as np
 # (re-exported here for the subsystems that historically imported it
 # from caching)
 from repro.core import telemetry
-from repro.core.comm import (HEADER_BYTES, QuantizedRows, Transport,
-                             WireCodec)
+from repro.core.comm import HEADER_BYTES, Transport, WireCodec
 from repro.graph.structure import Graph
 
 # sentinel version for "never written"; large-negative (not int64 min) so
@@ -298,53 +297,6 @@ class FeatureStore:
                              pad_rows=len(ids) - int(needed.sum()),
                              bytes=out.nbytes, parts=parts)
         return out
-
-    def fetch_masked_wire(self, ids: np.ndarray,
-                          needed: np.ndarray) -> QuantizedRows:
-        """:meth:`fetch_masked` in the int8 wire format: identical slot
-        alignment, hit/miss accounting, and traffic charges, but the
-        result stays quantized (:class:`QuantizedRows`) so the caller
-        can feed the int8-in/fp32-accumulate kernel directly.
-
-        Miss rows arrive via :meth:`Transport.send_wire` (charged, with
-        error feedback); local/hit rows are encoded in place — they
-        never cross the wire, so they cost nothing, but the batch is
-        uniformly quantized (each row within the codec's scale/2 error
-        bound of its fp32 value).  Unneeded/pad slots carry
-        ``q = mn = scale = 0`` and dequantize to exact zero rows,
-        matching :meth:`fetch_masked`.  Requires the int8 codec."""
-        if self.codec.name != "int8":
-            raise ValueError(
-                f"fetch_masked_wire requires the int8 codec (store has "
-                f"{self.codec.name!r})")
-        if self.g.features is None:
-            raise ValueError("fetch_masked_wire needs a feature matrix")
-        ids = np.asarray(ids)
-        needed = np.asarray(needed, bool) & (ids >= 0)
-        safe = np.maximum(ids, 0)
-        remote = needed & ~self._local_rows_mask(safe, needed)
-        hit = self.cached[safe] & remote
-        self.hits += int(hit.sum())
-        self._m_hits.inc(int(hit.sum()))
-        miss = remote & ~hit
-        miss_rows = int(miss.sum())
-        self.misses += miss_rows
-        self._m_misses.inc(miss_rows)
-        F = self.g.features.shape[1]
-        q = np.zeros((len(ids), F), np.uint8)
-        mn = np.zeros((len(ids), 1), np.float32)
-        scale = np.zeros((len(ids), 1), np.float32)
-        local = needed & ~miss
-        if int(local.sum()):
-            enc = self.codec.encode(
-                np.asarray(self.g.features[safe[local]], np.float32))
-            q[local], mn[local], scale[local] = enc.data
-        if miss_rows:
-            wire = self.transport.send_wire(
-                np.asarray(self.g.features[safe[miss]], np.float32),
-                row_ids=safe[miss])
-            q[miss], mn[miss], scale[miss] = wire.q, wire.mn, wire.scale
-        return QuantizedRows(q, mn, scale)
 
     def reset_stats(self) -> None:
         """Zero hit/miss counters and the transport's traffic counters

@@ -39,42 +39,12 @@ payloads).  Select with ``--wire-codec {fp32,bf16,int8}`` on
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, NamedTuple, Optional, Union
+from typing import Dict, Optional, Union
 
 import numpy as np
 
 from repro.core import telemetry
 
-
-class QuantizedRows(NamedTuple):
-    """An int8 wire batch kept in its wire format: ``q`` (n, F) uint8
-    codes with per-row affine metadata ``mn``/``scale`` (n, 1) float32;
-    row i dequantizes to ``mn[i] + q[i] * scale[i]``.
-
-    This is the type the int8-in/fp32-accumulate kernel path consumes
-    directly (:func:`repro.kernels.segment_sum.gather_scale_segment_sum_q_pallas`)
-    — :meth:`FeatureStore.fetch_masked_wire` hands fetched rows to the
-    aggregation without a decode round-trip.  Fields may be numpy or jax
-    arrays; as a NamedTuple it is automatically a jax pytree.
-    """
-    q: "np.ndarray"
-    mn: "np.ndarray"
-    scale: "np.ndarray"
-
-    @property
-    def num_rows(self) -> int:
-        return self.q.shape[0]
-
-    def rows(self, index) -> "QuantizedRows":
-        """Row-sliced view (same wire format)."""
-        return QuantizedRows(self.q[index], self.mn[index],
-                             self.scale[index])
-
-    def dequantize(self):
-        """The receiver's float32 view — identical math to
-        :meth:`Int8Codec.decode` (``mn + q * scale``)."""
-        return (self.mn + self.q.astype("float32") * self.scale
-                ).astype("float32")
 
 # per-RPC envelope cost of one remote transfer (DistDGL KVStore-style
 # request header: keys, shard route, lengths) — charged once per send
@@ -85,6 +55,12 @@ HEADER_BYTES = 64
 # int8 per-row affine metadata: row offset (min) + quantization step
 # (scale), one float32 each
 INT8_ROW_META_BYTES = 8
+
+# least int8 quantization step of a row whose max exceeds its min: a range
+# below 255 normal float32 steps would otherwise round the step to a
+# subnormal (which the chip flushes, and which rounding can make too small
+# for q to reach the max) or to 0
+_INT8_MIN_SCALE = np.float32(np.finfo(np.float32).tiny)
 
 
 # ---------------------------------------------------------------------------
@@ -256,6 +232,7 @@ class Int8Codec(WireCodec):
         mn = rows.min(axis=1, keepdims=True).astype(np.float32)
         mx = rows.max(axis=1, keepdims=True).astype(np.float32)
         scale = ((mx.astype(np.float64) - mn) / 255.0).astype(np.float32)
+        scale = np.where(mx > mn, np.maximum(scale, _INT8_MIN_SCALE), scale)
         safe = np.where(scale > 0, scale, 1.0).astype(np.float64)
         q = np.rint((rows.astype(np.float64) - mn) / safe)
         q = np.clip(np.where(scale > 0, q, 0.0), 0, 255).astype(np.uint8)
@@ -276,7 +253,8 @@ class Int8Codec(WireCodec):
         import jax.numpy as jnp
         mn = jnp.min(x, axis=-1, keepdims=True)
         mx = jnp.max(x, axis=-1, keepdims=True)
-        scale = (mx - mn) / 255.0
+        tiny = jnp.finfo(x.dtype).tiny
+        scale = jnp.where(mx > mn, jnp.maximum((mx - mn) / 255.0, tiny), 0.0)
         safe = jnp.where(scale > 0, scale, 1.0)
         q = jnp.clip(jnp.round((x - mn) / safe), 0.0, 255.0)
         return jnp.where(scale > 0, mn + q * scale, mn)
@@ -455,46 +433,6 @@ class Transport:
         self.requests += 1
         self._record(payload.nbytes, n)
         return out
-
-    def send_wire(self, rows: np.ndarray,
-                  row_ids: Optional[np.ndarray] = None) -> QuantizedRows:
-        """One RPC that hands the receiver the *wire format* instead of
-        the decoded view: identical accounting and error-feedback
-        residual updates to :meth:`send`, but the int8 payload is
-        returned as :class:`QuantizedRows` so the receiver can feed it
-        straight into the int8-in/fp32-accumulate kernel — no decode
-        round-trip through an HBM-resident fp32 feature matrix.
-
-        Only meaningful for the ``int8`` codec (the one wire format the
-        kernel consumes); other codecs raise."""
-        if self.codec.name != "int8":
-            raise ValueError(
-                f"send_wire requires the int8 codec (got "
-                f"{self.codec.name!r}); use send() for decoded rows")
-        rows = np.asarray(rows, np.float32)
-        if rows.ndim != 2:
-            raise ValueError(f"send_wire expects (n, dim) rows, got "
-                             f"{rows.shape}")
-        n, dim = rows.shape
-        if n == 0:
-            return QuantizedRows(np.zeros((0, dim), np.uint8),
-                                 np.zeros((0, 1), np.float32),
-                                 np.zeros((0, 1), np.float32))
-        res = self._residuals_for(dim)
-        if res is not None and row_ids is not None:
-            row_ids = np.asarray(row_ids)
-            pre = rows.astype(np.float64) + res.gather(row_ids)
-            payload = self.codec.encode(pre)
-            res.scatter(row_ids, pre - self.codec.decode(payload))
-        else:
-            payload = self.codec.encode(rows)
-        self.payload_bytes += payload.nbytes
-        self.header_bytes += HEADER_BYTES
-        self.rows_sent += n
-        self.requests += 1
-        self._record(payload.nbytes, n)
-        q, mn, scale = payload.data
-        return QuantizedRows(q, mn, scale)
 
     def account_opaque(self, n_rows: int, bytes_per_row: int) -> None:
         """Charge a send whose payload is not float rows (e.g. raw node

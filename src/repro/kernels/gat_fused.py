@@ -1,10 +1,10 @@
 """Pallas TPU kernel: one-pass fused GAT attention aggregation.
 
-The multi-pass GAT path (``segment_softmax`` + weighted ``segment_sum``)
-makes three HBM round-trips per layer: edge logits are materialized,
-re-read for the per-destination max/denominator, and the (E, heads)
-alpha tensor plus the (E, heads·hd) message tensor cross HBM again for
-the weighted reduction.  This kernel is the flash-attention treatment of
+The ``jax.ops`` GAT attention (``segment_softmax`` + weighted
+``segment_sum``) makes three HBM round-trips per layer: edge logits are
+materialized, re-read for the per-destination max/denominator, and the
+(E, heads) alpha tensor plus the (E, heads·hd) message tensor cross HBM
+again for the weighted reduction.  This kernel is the flash-attention treatment of
 that pipeline (``kernels/flash_attention.py`` is the in-repo exemplar):
 
     grid = (D/BN, E/BE), edge tiles innermost.  Per destination tile,
@@ -38,9 +38,9 @@ exists in neither pass.  Gradients match the ``segment_softmax``
 reference to ≤1e-5/param (asserted by ``tests/gat_train_check.py`` over
 {1, 2} devices).
 
-:func:`gat_fused_fits` is the VMEM capacity predicate; the
-:mod:`repro.kernels.ops` dispatch falls back to the multi-pass kernel
-path when the source slabs would not fit.
+:func:`gat_fused_fits` is the VMEM capacity predicate:
+``models/gnn/layers.py`` (``GATLayer``) takes this kernel where it holds
+and its ``jax.ops`` attention where the source slabs would not fit.
 """
 from __future__ import annotations
 
@@ -56,7 +56,7 @@ from repro.kernels.segment_sum import (DEFAULT_BE, DEFAULT_BN, LANE,
                                        SUBLANE, VMEM_BUDGET, _assert_vmem,
                                        _dot, _edge_column, _edge_dot,
                                        _fused_impl, _pad_edges, _pick_bf,
-                                       fused_vmem_floats, hbm_bytes_jax_ops)
+                                       fused_vmem_floats)
 
 NEG_INF = -1e30
 LEAKY_SLOPE = 0.2
@@ -185,9 +185,9 @@ def _gat_impl(hs, es, ed, edge_src, edge_dst, maskf, num_dst, heads, be,
 
 
 def _reference_alphas(es, ed, edge_src, edge_dst, maskf, num_dst):
-    """(E, heads) attention weights of the multi-pass reference (XLA ops;
-    the flash-style backward recomputes these instead of saving them;
-    scope ``edge_softmax``)."""
+    """(E, heads) attention weights in XLA ops, as the ``jax.ops``
+    attention forms them (the flash-style backward recomputes these
+    instead of saving them; scope ``edge_softmax``)."""
     with jax.named_scope("edge_softmax"):
         pre = (jnp.take(es, edge_src, axis=0)
                + jnp.take(ed, edge_dst, axis=0))           # (E, H)
@@ -301,70 +301,7 @@ def gat_fused_vmem_floats(num_src: int, num_dst: int, heads: int, hd: int,
 def gat_fused_fits(num_src: int, num_dst: int, heads: int, hd: int, *,
                    be: int = DEFAULT_BE, bn: int = DEFAULT_BN) -> bool:
     """True iff the one-pass GAT kernel (fwd + VJP) fits the VMEM budget
-    for these row counts — the capacity predicate behind the automatic
-    fused/multi-pass dispatch in :mod:`repro.kernels.ops`."""
+    for these row counts — the capacity predicate behind ``GATLayer``'s
+    choice between this kernel and its ``jax.ops`` attention."""
     return 4 * gat_fused_vmem_floats(num_src, num_dst, heads, hd, be=be,
                                      bn=bn) <= VMEM_BUDGET
-
-
-# ---------------------------------------------------------------------------
-# analytic HBM traffic models (the quantities BENCH_kernels.json reports)
-# ---------------------------------------------------------------------------
-
-def hbm_bytes_gat_multipass(E: int, heads: int, hd: int, num_dst: int,
-                            num_src: int, *, itemsize: int = 4) -> dict:
-    """Modeled HBM traffic of the multi-pass GAT reference
-    (``segment_softmax`` + weighted ``segment_sum``): the (E, heads)
-    logit/exp/alpha tensors are written and re-read around the
-    per-destination max and denominator reductions, and the
-    (E, heads·hd) message tensor crosses HBM in both passes."""
-    eh = E * heads * itemsize
-    msgs = E * heads * hd * itemsize
-    dh = num_dst * heads * itemsize
-    out = num_dst * heads * hd * itemsize
-    ids = E * 4
-    fwd = (2 * eh + ids            # gather es/ed -> write logits
-           + eh + dh              # segment_max reads logits, writes mx
-           + 2 * eh + dh          # exp: read logits+mx row, write ex
-           + eh + dh + ids        # denominator segment-sum
-           + 2 * eh + dh          # alpha = ex / den[dst]
-           + msgs + eh + msgs     # gather hs, scale by alpha, write msgs
-           + msgs + ids + out)    # weighted segment-sum
-    # backward re-materializes the same edge tensors (alpha saved or
-    # recomputed, message cotangents, softmax backward) — model it as
-    # the transpose of the forward traffic
-    bwd = fwd
-    return {"fwd": fwd, "bwd": bwd, "total": fwd + bwd}
-
-
-def hbm_bytes_gat_fused(E: int, heads: int, hd: int, num_dst: int,
-                        num_src: int, *, be: int = DEFAULT_BE,
-                        bn: int = DEFAULT_BN, itemsize: int = 4) -> dict:
-    """Modeled HBM traffic of :func:`gat_fused_attention_pallas`: the
-    hs/es slabs cross HBM once (constant block index), the ed tile once
-    per destination tile, ids+mask once per (dst-tile, edge-tile) pair —
-    no (E, ·) tensor is ever written.  The backward recomputes the
-    (E, heads) alphas once and reuses the fused/edge-dot kernels per
-    head."""
-    hdp = _pick_bf(hd)
-    hp = _pad8(heads)
-    sp = _pad8(num_src)
-    Ep = _pad_edges(E, be)
-    Np = -(-(num_dst + 1) // bn) * bn
-    n_tiles = Np // bn
-    eh = E * heads * itemsize
-    fwd = (sp * heads * hdp * itemsize         # hs slab once
-           + sp * hp * itemsize                # es slab once
-           + Np * hp * itemsize                # ed tiles once each
-           + n_tiles * Ep * 12                 # src+dst+mask per dst tile
-           + Np * heads * hdp * itemsize)      # write out
-    # alpha recompute (XLA, (E, heads) tensors) + per-head fused dh +
-    # edge-dot dalpha + two light (E, heads) segment sums
-    from repro.kernels.segment_sum import hbm_bytes_fused_kernel
-    per_head = hbm_bytes_fused_kernel(E, hd, num_src, num_dst, be=be,
-                                      bn=bn)["fwd"]
-    bwd = (4 * eh                              # recompute + dz/dpre terms
-           + heads * per_head                  # dhs via swapped fused
-           + (sp + _pad8(num_dst)) * hdp * itemsize + E * 4  # edge-dot
-           + 2 * eh)                           # des/ded segment sums
-    return {"fwd": fwd, "bwd": bwd, "total": fwd + bwd}

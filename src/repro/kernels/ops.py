@@ -33,7 +33,7 @@ def _interpret() -> bool:
 
 
 # VMEM-residency / tile-density of the most recently recorded edge
-# ordering (host-side: launchers and benches call record_tile_density;
+# ordering (host-side: the launchers call record_tile_density;
 # edge ids are tracers inside jit, so the wrappers cannot)
 _m_tile_active = telemetry.gauge(
     "kernel_tile_density", "blocked-kernel tile locality of the current "
@@ -44,9 +44,9 @@ _m_tile_rows = telemetry.gauge(
 
 def record_tile_density(edge_src, edge_dst, num_dst: int) -> dict:
     """Compute and publish the tile-density metrics of an edge ordering
-    (``--reorder`` moves these; the kernel byte models assume dense
-    tiles, so active_tile_frac is the fraction of that model actually
-    exercised).  Host-side numpy — call outside jit."""
+    (``--reorder`` moves these; the blocked kernels sweep every tile,
+    so active_tile_frac is the share of that sweep that meets an edge).
+    Host-side numpy — call outside jit."""
     d = _ss.edge_tile_density(edge_src, edge_dst, num_dst)
     _m_tile_active.set(d["active_tile_frac"])
     _m_tile_rows.set(d["src_rows_per_edge_tile"])
@@ -119,37 +119,6 @@ def gather_scale_segment_sum(h, edge_src, edge_dst, coef, num_dst: int):
                         interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("num_dst", "interpret"))
-def _gss_q_jit(q, mn, scale, edge_src, edge_dst, coef, num_dst: int,
-               interpret: bool):
-    return _ss.gather_scale_segment_sum_q_pallas(
-        q, mn, scale, edge_src, edge_dst, coef, num_dst,
-        interpret=interpret)
-
-
-def gather_scale_segment_sum_q(q, mn, scale, edge_src, edge_dst, coef,
-                               num_dst: int):
-    """int8-in / fp32-accumulate fused aggregation: source rows arrive
-    as wire-format uint8 codes + per-row (min, scale) metadata and are
-    dequantized inside the kernel per source slab — the fp32 feature
-    matrix never exists in HBM.  Forward-only (layer-0 data path).
-
-    Same capacity dispatch as :func:`gather_scale_segment_sum`: when the
-    slab does not fit, fall back to dequantize-in-XLA feeding the
-    blocked scatter kernel (correctness identical — the decode
-    round-trip saving is a fits-only optimization)."""
-    S, F = q.shape
-    interpret = _interpret()
-    if not _ss.fused_fits(S, num_dst, F):
-        with jax.named_scope("pallas_unfused"):
-            h = (mn + q.astype(jnp.float32) * scale).astype(jnp.float32)
-            return _gss_unfused_jit(h, edge_src, edge_dst, coef, num_dst,
-                                    interpret=interpret)
-    with jax.named_scope("pallas_fused"):
-        return _gss_q_jit(q, mn, scale, edge_src, edge_dst, coef, num_dst,
-                          interpret=interpret)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("num_dst", "heads", "interpret"))
 def _gat_fused_jit(hs, es, ed, edge_src, edge_dst, mask, num_dst: int,
@@ -158,37 +127,6 @@ def _gat_fused_jit(hs, es, ed, edge_src, edge_dst, mask, num_dst: int,
                                            edge_dst, mask, num_dst,
                                            heads=heads,
                                            interpret=interpret)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_dst", "heads", "interpret"))
-def _gat_multipass_jit(hs, es, ed, edge_src, edge_dst, mask,
-                       num_dst: int, heads: int, interpret: bool):
-    """The multi-pass kernel path the fused kernel replaces: logits and
-    alphas materialize as (E, heads) tensors (scope ``edge_softmax``); the
-    segment sums run through the blocked Pallas kernels."""
-    E = edge_src.shape[0]
-    hd = hs.shape[1] // heads
-    maskf = mask.astype(jnp.float32)
-    with jax.named_scope("edge_softmax"):
-        pre = (jnp.take(es, edge_src, axis=0)
-               + jnp.take(ed, edge_dst, axis=0))
-        logits = jax.nn.leaky_relu(pre, 0.2)
-        neg = jnp.asarray(-1e30, logits.dtype)
-        logits = jnp.where(maskf[:, None] > 0, logits, neg)
-        mx = jax.ops.segment_max(logits, edge_dst, num_dst,
-                                 indices_are_sorted=False)
-        ex = jnp.exp(logits - mx[edge_dst]) * maskf[:, None]
-        den = _ss.segment_sum_pallas(ex, edge_dst, num_dst,
-                                     interpret=interpret)
-        alpha = ex / (den[edge_dst] + 1e-9)
-    msgs = (jnp.take(hs.reshape(-1, heads, hd), edge_src, axis=0)
-            * alpha[..., None])
-    return _ss.segment_sum_pallas(msgs.reshape(E, heads * hd), edge_dst,
-                                  num_dst, interpret=interpret)
-
-
-_gat_fallback_warned: set = set()
 
 
 def gat_attention(hs, es, ed, edge_src, edge_dst, mask, num_dst: int, *,
@@ -202,27 +140,12 @@ def gat_attention(hs, es, ed, edge_src, edge_dst, mask, num_dst: int, *,
     online softmax so edge logits/alphas never reach HBM (see
     :mod:`repro.kernels.gat_fused`).
 
-    Capacity dispatch mirrors :func:`gather_scale_segment_sum`: when the
-    source slabs exceed the VMEM budget the multi-pass kernel path runs
-    instead, so ``use_kernel=True`` GAT never hits the VMEM assert."""
-    S = hs.shape[0]
-    hd = hs.shape[1] // heads
-    interpret = _interpret()
-    if not _gat.gat_fused_fits(S, num_dst, heads, hd):
-        key = (S, num_dst, heads, hd)
-        if key not in _gat_fallback_warned:
-            _gat_fallback_warned.add(key)
-            warnings.warn(
-                f"gat_attention: fused one-pass VMEM working set for "
-                f"num_src={S}, num_dst={num_dst}, heads={heads}, hd={hd} "
-                f"exceeds the budget; dispatching to the multi-pass "
-                f"kernel path (edge logits/alphas WILL cross HBM)")
-        with jax.named_scope("gat_multipass"):
-            return _gat_multipass_jit(hs, es, ed, edge_src, edge_dst, mask,
-                                      num_dst, heads, interpret=interpret)
+    Only for shapes where :func:`repro.kernels.gat_fused.gat_fused_fits`
+    holds: ``GATLayer`` runs its ``jax.ops`` attention elsewhere, and the
+    kernel asserts its VMEM budget."""
     with jax.named_scope("gat_fused"):
         return _gat_fused_jit(hs, es, ed, edge_src, edge_dst, mask,
-                              num_dst, heads, interpret=interpret)
+                              num_dst, heads, interpret=_interpret())
 
 
 @functools.partial(jax.jit,

@@ -484,113 +484,6 @@ def gather_scale_segment_sum_pallas(h: jax.Array, edge_src: jax.Array,
                   interpret)
 
 
-# ---------------------------------------------------------------------------
-# int8-in / fp32-accumulate variant: consume wire rows without a decode pass
-# ---------------------------------------------------------------------------
-
-META_COLS = 8          # (mn, scale) packed into a sublane-aligned block
-
-
-def _fused_q_kernel(src_ref, dst_ref, coef_ref, q_ref, meta_ref, out_ref,
-                    acc_ref, *, bn: int, sp: int):
-    n_i = pl.program_id(1)
-    e_i = pl.program_id(2)
-    ne = pl.num_programs(2)
-
-    onehot_s = (src_ref[:] == jax.lax.broadcasted_iota(
-        jnp.int32, (1, sp), 1)).astype(jnp.float32)    # (BE, Sp)
-    # dequantize the resident int8 slab in VMEM: the fp32 rows exist
-    # only here, never in HBM (the wire payload feeds the kernel as-is).
-    # Mosaic has no uint8 -> float32 cast; widening through int32 is exact
-    q = q_ref[:].astype(jnp.int32).astype(jnp.float32)  # (Sp, BF)
-    mn = meta_ref[:, 0:1]                              # (Sp, 1)
-    scale = meta_ref[:, 1:2]                           # (Sp, 1)
-    h = mn + q * scale
-    msgs = _dot(onehot_s, h)                           # (BE, BF)
-    msgs = msgs * coef_ref[:]                          # (BE, 1) broadcast
-
-    local = dst_ref[:] - n_i * bn                      # (BE, 1)
-    onehot_d = (local == jax.lax.broadcasted_iota(
-        jnp.int32, (1, bn), 1)).astype(jnp.float32)    # (BE, BN)
-    contrib = _dot(onehot_d.T, msgs)                   # (BN, BF)
-
-    @pl.when(e_i == 0)
-    def _init():
-        acc_ref[:] = contrib
-
-    @pl.when(e_i != 0)
-    def _acc():
-        acc_ref[:] = acc_ref[:] + contrib
-
-    @pl.when(e_i == ne - 1)
-    def _emit():
-        out_ref[:] = acc_ref[:].astype(out_ref.dtype)
-
-
-def gather_scale_segment_sum_q_pallas(q: jax.Array, mn: jax.Array,
-                                      scale: jax.Array,
-                                      edge_src: jax.Array,
-                                      edge_dst: jax.Array,
-                                      coef: jax.Array, num_dst: int, *,
-                                      be: int = DEFAULT_BE,
-                                      bn: int = DEFAULT_BN,
-                                      bf: int | None = None,
-                                      interpret: bool = True) -> jax.Array:
-    """int8-in / fp32-accumulate fused aggregation: like
-    :func:`gather_scale_segment_sum_pallas` but the source rows arrive in
-    the PR 5 wire format — ``q``: (num_src, F) uint8 codes with per-row
-    affine metadata ``mn``/``scale``: (num_src, 1) float32, row i
-    dequantizing to ``mn[i] + q[i] * scale[i]``.
-
-    Dequantization happens inside the kernel per source slab (the fp32
-    feature matrix is never materialized in HBM) and accumulation is
-    fp32, so the output matches decode-then-fp32 aggregation to the
-    codec's own error bound (≤ scale/2 per element before aggregation).
-    Forward-only by design: it sits on the layer-0 data path where the
-    quantized inputs carry no gradient (differentiable paths go through
-    :func:`gather_scale_segment_sum_pallas` on decoded rows).
-    """
-    S, F = q.shape
-    bf = _pick_bf(F) if bf is None else bf
-    _assert_vmem(fused_vmem_floats(S, num_dst, F, be=be, bn=bn, bf=bf)
-                 + (-(-S // SUBLANE) * SUBLANE) * META_COLS,
-                 what="gather_scale_segment_sum_q_pallas")
-    E = edge_src.shape[0]
-    Ep = _pad_edges(E, be)
-    Fp = -(-F // bf) * bf
-    Sp = -(-S // SUBLANE) * SUBLANE
-    pad_seg = num_dst
-    Np = -(-(num_dst + 1) // bn) * bn
-
-    q_p = jnp.zeros((Sp, Fp), jnp.uint8).at[:S, :F].set(
-        q.astype(jnp.uint8))
-    # pad rows keep mn = scale = 0 so they dequantize to exact zeros
-    meta_p = jnp.zeros((Sp, META_COLS), jnp.float32)
-    meta_p = meta_p.at[:S, 0:1].set(mn.astype(jnp.float32))
-    meta_p = meta_p.at[:S, 1:2].set(scale.astype(jnp.float32))
-    src_p = _edge_column(edge_src, Ep, 0, jnp.int32)
-    dst_p = _edge_column(edge_dst, Ep, pad_seg, jnp.int32)
-    coef_p = _edge_column(coef, Ep, 0, jnp.float32)
-
-    grid = (Fp // bf, Np // bn, Ep // be)
-    out = pl.pallas_call(
-        functools.partial(_fused_q_kernel, bn=bn, sp=Sp),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((be, 1), lambda f, n, e: (e, 0)),
-            pl.BlockSpec((be, 1), lambda f, n, e: (e, 0)),
-            pl.BlockSpec((be, 1), lambda f, n, e: (e, 0)),
-            pl.BlockSpec((Sp, bf), lambda f, n, e: (0, f)),
-            pl.BlockSpec((Sp, META_COLS), lambda f, n, e: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bn, bf), lambda f, n, e: (n, f)),
-        out_shape=jax.ShapeDtypeStruct((Np, Fp), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32)],
-        interpret=interpret,
-    )(src_p, dst_p, coef_p, q_p, meta_p)
-    return out[:num_dst, :F]
-
-
 def edge_tile_density(edge_src, edge_dst, num_dst: int, *,
                       be: int = DEFAULT_BE, bn: int = DEFAULT_BN) -> dict:
     """Pure-numpy VMEM-residency / tile-density metrics of the blocked
@@ -659,119 +552,3 @@ def fused_fits(num_src: int, num_dst: int, F: int, *,
     fused/unfused dispatch in :mod:`repro.kernels.ops`."""
     return 4 * fused_vmem_floats(num_src, num_dst, F, be=be, bn=bn,
                                  bf=bf) <= VMEM_BUDGET
-
-
-# ---------------------------------------------------------------------------
-# analytic HBM traffic models (the roofline the bench reports)
-# ---------------------------------------------------------------------------
-
-def _tiles(n: int, b: int) -> int:
-    return max(-(-n // b), 1)
-
-
-def hbm_bytes_jax_ops(E: int, F: int, num_dst: int, *,
-                      itemsize: int = 4) -> dict:
-    """Modeled HBM traffic of the unfused XLA path (``jnp.take`` then
-    ``jax.ops.segment_sum``): the (E, F) message tensor is written and
-    re-read around the scatter, and the backward gathers/scatters it
-    again.  Terms per pass are listed in the returned dict."""
-    msgs = E * F * itemsize
-    out = num_dst * F * itemsize
-    ids = E * 4
-    fwd = (msgs          # gather reads E source rows
-           + msgs        # write materialized messages
-           + msgs + ids  # scatter-add re-reads messages + ids
-           + out)        # write aggregate
-    bwd = (out           # read grad_out
-           + msgs        # gather grad_out[seg_ids] -> grad_msgs (write)
-           + msgs + ids  # unscale/scatter grad_msgs back to sources
-           + msgs)       # write dh
-    return {"fwd": fwd, "bwd": bwd, "total": fwd + bwd}
-
-
-def hbm_bytes_unfused_kernel(E: int, F: int, num_dst: int, *,
-                             be: int = DEFAULT_BE, bn: int = DEFAULT_BN,
-                             bf: int | None = None,
-                             itemsize: int = 4) -> dict:
-    """Modeled HBM traffic of XLA gather+scale followed by the blocked
-    Pallas scatter kernel.  The scatter grid (N/BN, F/BF, E/BE) re-reads
-    every edge tile once per *node* tile — the price of keeping output
-    tiles resident — and the backward gather grid (E/BE, F/BF, N/BN)
-    dually re-reads grad_out once per edge tile."""
-    bf = _pick_bf(F) if bf is None else bf
-    Fp = _tiles(F, bf) * bf
-    Ep = _pad_edges(E, be)
-    Np = _tiles(num_dst + 1, bn) * bn
-    n_tiles = Np // bn
-    e_tiles = Ep // be
-    f_tiles = Fp // bf
-    msgs = E * F * itemsize
-    fwd = (msgs                            # XLA gather reads source rows
-           + Ep * Fp * itemsize           # write padded messages
-           + n_tiles * (Ep * Fp * itemsize            # kernel re-reads
-                        + f_tiles * Ep * 4)           # msgs + ids per n
-           + Np * Fp * itemsize)          # write aggregate
-    bwd = (e_tiles * (Np * Fp * itemsize              # grad_out per e
-                      + f_tiles * Ep * 4)             # ids
-           + Ep * Fp * itemsize           # write grad_msgs
-           + 2 * msgs)                    # XLA unscale/scatter to dh
-    return {"fwd": fwd, "bwd": bwd, "total": fwd + bwd}
-
-
-def hbm_bytes_fused_kernel(E: int, F: int, num_dst: int, num_src: int, *,
-                           be: int = DEFAULT_BE, bn: int = DEFAULT_BN,
-                           bf: int | None = None,
-                           itemsize: int = 4) -> dict:
-    """Modeled HBM traffic of :func:`gather_scale_segment_sum_pallas`.
-    The source slab crosses HBM once per feature tile (its block index is
-    constant over the inner (n, e) sweep); edge ids + coef are re-read
-    once per (feature, node) tile pair; the (E, F) message tensor
-    contributes nothing.  Backward = the same kernel (src/dst swapped)
-    plus the edge-dot kernel, which re-reads both feature slabs once per
-    edge tile."""
-    bf = _pick_bf(F) if bf is None else bf
-    Fp = _tiles(F, bf) * bf
-    Ep = _pad_edges(E, be)
-    Np = _tiles(num_dst + 1, bn) * bn
-    Sp = _tiles(num_src, SUBLANE) * SUBLANE
-    n_tiles = Np // bn
-    e_tiles = Ep // be
-    f_tiles = Fp // bf
-
-    def one_fused(sp, np_):
-        return (sp * Fp * itemsize                      # source slab once
-                + f_tiles * (np_ // bn) * Ep * 12       # src+dst+coef
-                + np_ * Fp * itemsize)                  # write out
-
-    fwd = one_fused(Sp, Np)
-    Gp = _tiles(num_dst, SUBLANE) * SUBLANE      # bwd slab = grad_out
-    Np_b = _tiles(num_src + 1, bn) * bn
-    edge_dot = (e_tiles * (Sp + Gp) * Fp * itemsize     # both slabs per e
-                + f_tiles * Ep * 8 + Ep * itemsize)     # ids + dcoef out
-    bwd = one_fused(Gp, Np_b) + edge_dot
-    return {"fwd": fwd, "bwd": bwd, "total": fwd + bwd}
-
-
-def hbm_bytes_fused_q_kernel(E: int, F: int, num_dst: int, num_src: int, *,
-                             be: int = DEFAULT_BE, bn: int = DEFAULT_BN,
-                             bf: int | None = None) -> dict:
-    """Modeled HBM traffic of :func:`gather_scale_segment_sum_q_pallas`
-    (forward-only).  The source slab crosses HBM at 1 byte/element plus
-    8 bytes/row of metadata instead of 4 bytes/element — AND the
-    decode round-trip of the wire path (read q, write fp32 rows, re-read
-    them in the kernel) disappears entirely."""
-    bf = _pick_bf(F) if bf is None else bf
-    Fp = _tiles(F, bf) * bf
-    Ep = _pad_edges(E, be)
-    Np = _tiles(num_dst + 1, bn) * bn
-    Sp = _tiles(num_src, SUBLANE) * SUBLANE
-    f_tiles = Fp // bf
-    fwd = (Sp * Fp * 1                              # int8 slab once
-           + f_tiles * Sp * META_COLS * 4           # metadata per f tile
-           + f_tiles * (Np // bn) * Ep * 12         # src+dst+coef
-           + Np * Fp * 4)                           # write fp32 out
-    # what the decode-then-fp32 path would have paid on top of the
-    # fp32 fused kernel: read q + meta, write the fp32 feature matrix
-    decode_roundtrip = num_src * F * 1 + num_src * 8 + num_src * F * 4
-    return {"fwd": fwd, "total": fwd,
-            "decode_roundtrip_avoided": decode_roundtrip}

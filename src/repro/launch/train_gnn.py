@@ -455,14 +455,7 @@ def run(args):
                 # are byte-accounted and arrive wire-decoded (zero rows at
                 # pads — pad slots never aggregate, training is unaffected)
                 src = mb.blocks[0].src_nodes
-                if args.wire_codec == "int8" and args.use_kernel:
-                    # int8-in path: rows stay in wire format all the way
-                    # into the aggregation kernel, which dequantizes per
-                    # source slab — no decode round-trip (layers that
-                    # project before aggregating decode on device)
-                    x_in = store.fetch_masked_wire(src, src >= 0)
-                else:
-                    x_in = jnp.asarray(store.fetch_masked(src, src >= 0))
+                x_in = jnp.asarray(store.fetch_masked(src, src >= 0))
                 y = jnp.asarray(g.labels[seeds])
                 params, ostate, loss = step(params, ostate, blocks, x_in,
                                             y, jnp.ones_like(y, jnp.float32))

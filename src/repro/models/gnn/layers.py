@@ -16,7 +16,7 @@ import numpy as np
 from repro.core.abstraction import (DeviceGraph, MessagePassing,
                                     gather_scale_segment_sum,
                                     segment_softmax)
-from repro.core.comm import QuantizedRows
+from repro.kernels import gat_fused
 
 
 def _dense(key, din, dout):
@@ -36,8 +36,6 @@ class GCNLayer(MessagePassing):
 
     def __call__(self, p, g: DeviceGraph, x_src, x_dst=None, *,
                  use_kernel=False):
-        if isinstance(x_src, QuantizedRows):
-            x_src = jnp.asarray(x_src.dequantize())   # projects first
         with jax.named_scope("gnn.dense"):
             h = x_src @ p["w"]
         with jax.named_scope("gnn.norm"):
@@ -60,11 +58,7 @@ class SAGELayer(MessagePassing):
     gather→scale→segment-sum (mask as the per-edge coefficient, degree
     normalization after) — same math as the previous ``segment_mean``
     path, but on the kernel path the (E, F) message tensor stays in
-    VMEM, and because features aggregate *before* any projection,
-    layer 0 can consume :class:`~repro.core.comm.QuantizedRows` int8
-    wire rows directly: the kernel dequantizes per source slab, so the
-    wire fetch never takes a decode round-trip through HBM
-    (``--wire-codec int8 --use-kernel``)."""
+    VMEM."""
 
     aggregate = "mean"
 
@@ -82,13 +76,8 @@ class SAGELayer(MessagePassing):
     def __call__(self, p, g: DeviceGraph, x_src, x_dst=None, *,
                  use_kernel=False):
         if x_dst is None:
-            # the self path needs fp32 rows; only the num_dst prefix
-            # is ever dequantized host-side on the int8-in path
             with jax.named_scope("gnn.dense"):
-                x_dst = (jnp.asarray(
-                    x_src.rows(slice(0, g.num_dst)).dequantize())
-                    if isinstance(x_src, QuantizedRows)
-                    else x_src[:g.num_dst])
+                x_dst = x_src[:g.num_dst]
         with jax.named_scope("gnn.norm"):
             coef = g.edge_mask.astype(jnp.float32)
         agg = gather_scale_segment_sum(x_src, g.edge_src, g.edge_dst,
@@ -142,10 +131,6 @@ class GATLayer(MessagePassing):
 
     def __call__(self, p, g: DeviceGraph, x_src, x_dst=None, *,
                  use_kernel=False):
-        if isinstance(x_src, QuantizedRows):
-            # attention projects before aggregating, so the int8-in
-            # kernel path does not apply — decode up front
-            x_src = jnp.asarray(x_src.dequantize())
         prefix = x_dst is None
         if prefix:
             x_dst = x_src[:g.num_dst]
@@ -162,9 +147,10 @@ class GATLayer(MessagePassing):
             dst = jnp.concatenate([g.edge_dst, loops])
             mask = jnp.concatenate([g.edge_mask & (g.edge_src != g.edge_dst),
                                     jnp.ones((g.num_dst,), bool)])
-            if use_kernel:
-                # one-pass fused online-softmax kernel where its slabs fit
-                # VMEM, else the multi-pass kernel path
+            if use_kernel and gat_fused.gat_fused_fits(
+                    x_src.shape[0], g.num_dst, heads, c):
+                # the one-pass fused online-softmax kernel, where its
+                # slabs fit VMEM
                 from repro.kernels import ops as kops
                 out = kops.gat_attention(hs, es, ed, src, dst, mask,
                                          g.num_dst, heads=heads)
@@ -226,8 +212,6 @@ class GGNNLayer(MessagePassing):
                 "b": jnp.zeros((3 * dout,), jnp.float32)}
 
     def __call__(self, p, g, x_src, x_dst=None, *, use_kernel=False):
-        if isinstance(x_src, QuantizedRows):
-            x_src = jnp.asarray(x_src.dequantize())   # projects first
         with jax.named_scope("gnn.dense"):
             if p.get("proj") is not None:
                 x_src = x_src @ p["proj"]

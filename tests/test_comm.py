@@ -8,7 +8,7 @@ from repro.core.comm import (CODECS, HEADER_BYTES, INT8_ROW_META_BYTES,
                              Transport, resolve_codec)
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:                      # unit tests still run without it
     HAVE_HYPOTHESIS = False
@@ -16,7 +16,7 @@ except ImportError:                      # unit tests still run without it
     def given(*a, **k):                  # noqa: D103 - stub decorator
         return lambda f: pytest.mark.skip("hypothesis not installed")(f)
 
-    settings = given
+    settings = example = given
 
     class st:                            # noqa: D101 - stub strategies
         floats = integers = lists = staticmethod(lambda *a, **k: None)
@@ -90,6 +90,27 @@ def test_int8_roundtrip_error_bound_and_wire_size():
 def test_int8_constant_row_is_exact():
     x = np.full((2, 9), 3.25, np.float32)
     np.testing.assert_array_equal(CODECS["int8"].qdq(x), x)
+
+
+@pytest.mark.parametrize("qdq", ["host", "jax"])
+def test_int8_range_below_normal_steps_keeps_a_nonzero_step(qdq):
+    """A row whose range is under 255 normal float32 steps quantizes with
+    the smallest normal float32 as its step, neither 0 nor a subnormal
+    (which the chip flushes), so every element stays within half a
+    step."""
+    import jax.numpy as jnp
+    x = np.asarray([[0.0, 8.688e-44, 1e-40, 3e-39]], np.float32)
+    c = CODECS["int8"]
+    tiny = float(np.finfo(np.float32).tiny)
+    if qdq == "host":
+        p = c.encode(x)
+        assert float(p.data[2][0, 0]) == tiny
+        d = c.decode(p)
+    else:
+        d = np.asarray(c.jax_qdq(jnp.asarray(x)))
+        assert c.jax_qdq(jnp.asarray(x, jnp.bfloat16)).dtype == jnp.bfloat16
+    assert d.dtype == np.float32
+    assert (np.abs(d.astype(np.float64) - x) <= tiny / 2).all()
 
 
 @pytest.mark.parametrize("codec", ["fp32", "bf16", "int8"])
@@ -217,6 +238,7 @@ finite_f32 = st.floats(min_value=-F32_MAX, max_value=F32_MAX,
 
 @settings(deadline=None, max_examples=60)
 @given(st.lists(finite_f32, min_size=2, max_size=24))
+@example([0.0, 8.688e-44])       # range a subnormal: its step underflowed
 def test_int8_error_at_most_half_scale_any_finite_row(row):
     """Property (a): per-element int8 encode/decode error <= scale/2 for
     arbitrary finite float32 rows (plus float32 representation spacing —

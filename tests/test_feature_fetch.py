@@ -111,6 +111,33 @@ def test_codec_miss_rows_and_bytes(path, codec):
                                        + HEADER_BYTES)
 
 
+@pytest.mark.parametrize("mix", ["all_hits", "all_misses", "half_cached"])
+def test_int8_hits_exact_misses_decoded_pads_zero(path, mix):
+    """The int8 codec touches only the rows that cross the wire: cache
+    hits come back bit for bit, misses as the codec decodes them, and
+    slots not needed as +0.0 rows."""
+    g = _graph()
+    ids, needed = LAYOUTS["pad_runs_mid_and_tail"]
+    real = np.unique(ids[ids >= 0])
+    cached = {"all_hits": real, "all_misses": np.zeros(0, np.int64),
+              "half_cached": real[::2]}[mix]
+    store = C.FeatureStore(g, cached, codec="int8")
+    out = store.fetch_masked(ids, needed)
+    want = _want(g, ids, needed)
+    slot = needed & (ids >= 0)
+    hit = slot & np.isin(ids, cached)
+    miss = slot & ~hit
+    assert hit.any() == (mix != "all_misses")
+    assert miss.any() == (mix != "all_hits")
+    assert np.array_equal(_bits(out[~slot]), _bits(np.zeros_like(out[~slot])))
+    assert np.array_equal(_bits(out[hit]), _bits(want[hit]))
+    ref = Transport("int8", n_rows=N, path="test.reference")
+    assert np.array_equal(_bits(out[miss]),
+                          _bits(ref.send(want[miss], row_ids=ids[miss])))
+    assert (store.hits, store.misses) == (int(hit.sum()), int(miss.sum()))
+    assert store.requests == int(miss.any())
+
+
 def test_identity_codec_accounts_misses_without_a_send(path, monkeypatch):
     g = _graph()
     ids, needed = LAYOUTS["no_pads"]

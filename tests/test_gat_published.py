@@ -3,6 +3,7 @@
 concatenated hidden heads, a head-mean last layer, a bias, a skip path
 and ELU between layers, in every implementation of the edge attention,
 over padded sampled blocks and over the full graph."""
+import functools
 import os
 import sys
 
@@ -16,12 +17,13 @@ from repro.core.abstraction import DeviceGraph
 from repro.graph.structure import from_edges
 from repro.kernels import gat_fused, ops as kops
 from repro.models.gnn import model as GM
+from repro.models.gnn.layers import GATLayer
 from repro.models.gnn.model import GNNConfig
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from chipbench.references import gat as REF  # noqa: E402
 
-IMPLS = ("jax_ops", "gat_fused", "gat_multipass")
+IMPLS = ("jax_ops", "gat_fused", "gat_kernel_unfit")
 # Both sides are float32 on the CPU, where every product is exact float32:
 # they differ only in the order of their sums (segment sums over a few
 # edges, the head mean, the reference's per-head aggregation), a few ulp
@@ -46,17 +48,17 @@ def graph(graph):
 
 @pytest.fixture
 def impl(request, monkeypatch):
-    """Route ``use_kernel`` to one implementation, and count the calls
-    that reach it."""
+    """Route the attention to one implementation, and count the calls that
+    reach the kernel.  ``gat_kernel_unfit`` sets ``use_kernel`` where the
+    kernel's slabs do not fit VMEM: the layer then runs its ``jax.ops``
+    attention."""
     calls = []
-    if request.param == "gat_multipass":
+    if request.param == "gat_kernel_unfit":
         monkeypatch.setattr(gat_fused, "gat_fused_fits",
                             lambda *a, **k: False)
-    if request.param != "jax_ops":
-        name = f"_{request.param}_jit"
-        real = getattr(kops, name)
-        monkeypatch.setattr(kops, name,
-                            lambda *a, **k: calls.append(1) or real(*a, **k))
+    real = kops._gat_fused_jit
+    monkeypatch.setattr(kops, "_gat_fused_jit",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
     return request.param, calls
 
 
@@ -122,15 +124,15 @@ def test_blocks_match_the_reference(graph, impl):
         lr, gr = jax.value_and_grad(REF.loss)(params, ref_batch, "highest")
     assert abs(float(lp) - float(lr)) <= RTOL * abs(float(lr))
     _close(gp, gr)
-    # one attention call a layer, forward; the kernels' backward is their own
-    assert len(calls) == (0 if name == "jax_ops" else 3)
+    # one kernel call a layer, forward; the kernel's backward is its own
+    assert len(calls) == (3 if name == "gat_fused" else 0)
 
 
 @pytest.mark.parametrize("impl", IMPLS, indirect=True)
 def test_full_graph_matches_the_reference(graph, impl):
     """``forward_full`` over a graph with a few self-loops of its own: the
     layer drops them and adds one per node, as PyG does."""
-    name, _ = impl
+    name, calls = impl
     e = graph.edges()
     loops = np.array([[0, 0], [7, 7], [9, 9]])
     g = from_edges(graph.num_nodes, np.concatenate([e, loops]),
@@ -157,6 +159,28 @@ def test_full_graph_matches_the_reference(graph, impl):
         lr, gr = jax.value_and_grad(REF.loss)(params, batch, "highest")
     assert abs(float(lp) - float(lr)) <= RTOL * abs(float(lr))
     _close(gp, gr)
+    assert len(calls) == (3 if name == "gat_fused" else 0)
+
+
+def test_unfit_kernel_lowers_as_jax_ops():
+    """Where the kernel's slabs do not fit VMEM (ogbn-products' first
+    layer: 681,472 sources, 4 heads of 128), ``use_kernel=True`` lowers
+    the layer to the very program ``use_kernel=False`` does."""
+    S, D, E, heads, c = 681_472, 61_952, 619_520, 4, 128
+    assert not gat_fused.gat_fused_fits(S, D, heads, c)
+    spec = jax.ShapeDtypeStruct
+    g = DeviceGraph(spec((E,), jnp.int32), spec((E,), jnp.int32),
+                    spec((E,), jnp.bool_), S, D, spec((D,), jnp.float32),
+                    spec((S,), jnp.float32))
+    p = jax.eval_shape(lambda k: GATLayer.init(k, 100, heads * c, heads),
+                       jax.random.PRNGKey(0))
+    x = spec((S, 100), jnp.float32)
+
+    def text(use_kernel):
+        f = functools.partial(GATLayer(), use_kernel=use_kernel)
+        return jax.jit(f).lower(p, g, x).as_text()
+
+    assert text(True) == text(False)
 
 
 def test_each_published_part_is_in_the_function(graph):

@@ -11,11 +11,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import caching as C
+from repro.core.abstraction import gather_scale_segment_sum
+from repro.core.comm import CODECS
+from repro.graph.structure import from_edges
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.gat_fused import gat_fused_attention_pallas
 from repro.kernels.segment_sum import (gather_scale_segment_sum_pallas,
-                                       gather_scale_segment_sum_q_pallas,
                                        segment_sum_pallas)
 from repro.kernels.ssd_chunk import ssd_chunk_state_pallas
 
@@ -296,71 +299,46 @@ def test_gat_fused_single_neighbor_copies_source_row():
 
 
 # ---------------------------------------------------------------------------
-# int8-in / fp32-accumulate aggregation
+# int8 wire rows into the aggregation kernel
 # ---------------------------------------------------------------------------
 
-def _quantize_rows(h):
-    mn = h.min(axis=1, keepdims=True)
-    scale = np.maximum((h.max(axis=1, keepdims=True) - mn) / 255.0, 1e-12)
-    q = np.rint((h - mn) / scale).astype(np.uint8)
-    return q, mn.astype(np.float32), scale.astype(np.float32)
-
-
-@pytest.mark.parametrize("S,E,F,N", [(50, 200, 33, 40), (16, 64, 128, 16),
-                                     (130, 300, 5, 71)])
-def test_int8_in_matches_decode_then_fp32(S, E, F, N):
-    """The quantized kernel dequantizes per source slab in VMEM — it
-    must agree with decode-to-fp32-then-aggregate to fp32 roundoff
-    (same affine, same accumulation order)."""
-    rng = np.random.default_rng(7)
-    h = rng.normal(size=(S, F)).astype(np.float32)
-    q, mn, scale = _quantize_rows(h)
-    src = jnp.asarray(rng.integers(0, S, E), jnp.int32)
-    dst = jnp.asarray(rng.integers(0, N, E), jnp.int32)
-    coef = jnp.asarray(rng.normal(size=(E,)), jnp.float32)
-    got = gather_scale_segment_sum_q_pallas(
-        jnp.asarray(q), jnp.asarray(mn), jnp.asarray(scale), src, dst,
-        coef, N)
-    decoded = mn + q.astype(np.float32) * scale
-    want = gather_scale_segment_sum_pallas(jnp.asarray(decoded), src,
-                                           dst, coef, N)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
-
-
-def test_int8_in_error_bound_vs_fp32_truth():
-    """Against the TRUE fp32 aggregation, the int8-in result is bounded
-    by the codec's per-row quantization error: |err| <= sum over
-    contributing edges of |coef_e| * scale_src[e] / 2, row-feature-wise."""
+def test_int8_fetched_rows_aggregate_within_the_codec_bound():
+    """Rows an int8 ``FeatureStore`` hands back, aggregated by the kernel
+    path: against the float32 truth, each output row is off by at most
+    the sum over its miss-row edges of |coef| * scale / 2 (the codec's
+    per-element bound); cache hits come back exact, so an output fed
+    by hits alone matches to float32 roundoff."""
     rng = np.random.default_rng(11)
     S, E, F, N = 40, 160, 24, 30
-    h = rng.normal(size=(S, F)).astype(np.float32)
-    q, mn, scale = _quantize_rows(h)
+    feats = rng.normal(size=(S, F)).astype(np.float32)
+    g = from_edges(S, np.stack([np.arange(S), np.roll(np.arange(S), 1)], 1),
+                   features=feats)
+    cached = np.arange(0, S, 2)
+    store = C.FeatureStore(g, cached, codec="int8")
+    ids = np.arange(S)
+    x = store.fetch_masked(ids, np.ones(S, bool))
+    hit = np.isin(ids, cached)
+    assert np.array_equal(x[hit], feats[hit])
+    assert not np.array_equal(x[~hit], feats[~hit])
+    scale = CODECS["int8"].encode(feats).data[2][:, 0].astype(np.float64)
+    scale[hit] = 0.0
+
     src = rng.integers(0, S, E)
     dst = rng.integers(0, N, E)
     coef = rng.normal(size=(E,)).astype(np.float32)
-    got = np.asarray(gather_scale_segment_sum_q_pallas(
-        jnp.asarray(q), jnp.asarray(mn), jnp.asarray(scale),
-        jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32),
-        jnp.asarray(coef), N))
+    got = np.asarray(gather_scale_segment_sum(
+        jnp.asarray(x), jnp.asarray(src, jnp.int32),
+        jnp.asarray(dst, jnp.int32), jnp.asarray(coef), N,
+        use_kernel=True))
     truth = np.zeros((N, F), np.float64)
-    np.add.at(truth, dst, h[src].astype(np.float64) * coef[:, None])
+    np.add.at(truth, dst, feats[src].astype(np.float64) * coef[:, None])
     bound = np.zeros((N,), np.float64)
-    np.add.at(bound, dst,
-              np.abs(coef) * (scale[src, 0] / 2.0 + 1e-7))
+    np.add.at(bound, dst, np.abs(coef) * scale[src] / 2.0)
     err = np.abs(got - truth).max(axis=1)
     assert (err <= bound + 1e-5).all(), (err - bound).max()
-
-
-def test_int8_in_no_edges():
-    q = jnp.zeros((9, 6), jnp.uint8)
-    mn = jnp.zeros((9, 1), jnp.float32)
-    sc = jnp.ones((9, 1), jnp.float32)
-    z = jnp.zeros((0,), jnp.int32)
-    out = gather_scale_segment_sum_q_pallas(
-        q, mn, sc, z, z, jnp.zeros((0,), jnp.float32), 5)
-    assert out.shape == (5, 6)
-    assert float(jnp.abs(out).sum()) == 0.0
+    only_hits = np.ones(N, bool)
+    only_hits[dst[~hit[src]]] = False
+    assert only_hits.any() and (err[only_hits] <= 1e-5).all()
 
 
 # ---------------------------------------------------------------------------

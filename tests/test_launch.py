@@ -1,5 +1,6 @@
 """Launcher contracts: device counts are never clamped, and the entry
 points keep JAX's compile cache at one fixed place."""
+import math
 import os
 
 import jax
@@ -34,3 +35,15 @@ def test_compile_cache_env_wins_else_checkout_dir(monkeypatch):
         assert jax.config.jax_compilation_cache_dir == path
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_minibatch_int8_wire_with_kernels_trains(monkeypatch):
+    """``--wire-codec int8 --use-kernel`` on the single-device mini-batch
+    path: miss rows arrive decoded, the Pallas kernels aggregate them."""
+    monkeypatch.setenv("XLA_FLAGS",
+                       "--xla_force_host_platform_device_count=1")
+    loss = train_gnn.main(["--minibatch", "--arch", "sage",
+                           "--wire-codec", "int8", "--use-kernel",
+                           "--nodes", "64", "--epochs", "1",
+                           "--batch", "16"])
+    assert math.isfinite(loss)

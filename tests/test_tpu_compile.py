@@ -108,21 +108,6 @@ def test_fused_gather_scale_segment_sum_compiles(compile_for_chip):
     assert "tpu_custom_call" in text
 
 
-def test_int8_in_fused_compiles(compile_for_chip):
-    D = _largest_block(lambda s, d: SS.fused_fits(s, d, FEAT))
-    S, E = 6 * D, FANOUT * D
-
-    def f(q, mn, scale, src, dst, coef):
-        return SS.gather_scale_segment_sum_q_pallas(
-            q, mn, scale, src, dst, coef, D, interpret=False)
-
-    text = compile_for_chip(f, ((S, FEAT), jnp.uint8),
-                            ((S, 1), jnp.float32), ((S, 1), jnp.float32),
-                            ((E,), jnp.int32), ((E,), jnp.int32),
-                            ((E,), jnp.float32))
-    assert "tpu_custom_call" in text
-
-
 def test_gat_fused_attention_compiles(compile_for_chip):
     hd = HIDDEN // HEADS
     D = _largest_block(lambda s, d: GF.gat_fused_fits(s, d, HEADS, hd))
